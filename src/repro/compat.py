@@ -1,39 +1,31 @@
-"""Version-compatibility shims for the jax APIs this repo leans on.
+"""The jax surfaces this repo wraps, written for the installed jax (0.9.0,
+with libtpu 0.0.34 for the TPU v5e target).
 
-The reproduction targets the pinned container jax (0.4.37 today) while the
-code is written against the modern surface; every API that moved, was
-renamed, or changed signature between jax 0.4.x and 0.6+ is centralized here
-behind a stable function.  Nothing outside this module may touch
-``jax.experimental.pallas.tpu`` attributes or version-gated ``jax.sharding``
-lookups directly — kernels go through :mod:`repro.kernels.dispatch`, which in
-turn goes through here.
+Nothing outside this module may touch ``jax.experimental.pallas.tpu``
+attributes or version-gated ``jax.sharding`` lookups directly — kernels go
+through :mod:`repro.kernels.dispatch`, which in turn goes through here.
 
-Shimmed surfaces
+Wrapped surfaces
 ----------------
-- ``jax.sharding.get_abstract_mesh`` (added ~0.5): :func:`get_abstract_mesh`
-  falls back to the thread-local physical mesh that ``with mesh:`` installs
-  on 0.4.x.
-- ``AbstractMesh`` constructor: 0.4.x takes ``((name, size), ...)``, newer
-  jax takes ``(sizes, names)`` — :func:`make_abstract_mesh` accepts the
-  modern form everywhere.
-- ``pltpu.TPUCompilerParams`` -> ``pltpu.CompilerParams`` rename:
-  :func:`tpu_compiler_params` builds whichever class exists and silently
-  drops kwargs the pinned class does not know.
-- pallas-TPU availability: CPU-only jaxlib builds may lack the mosaic
-  lowering entirely; ``HAS_PALLAS_TPU`` gates it and :func:`pallas_tpu`
-  raises a actionable error instead of an AttributeError mid-kernel.
-- tree utils: ``jax.tree.map`` only exists from 0.4.26; :func:`tree_map`
-  always works.
+- pallas TPU: :func:`tpu_compiler_params`, :func:`vmem`,
+  :func:`prefetch_scalar_grid_spec`.  ``HAS_PALLAS_TPU`` says whether the
+  Mosaic lowering imported; :func:`pallas_tpu` raises an actionable error
+  instead of an AttributeError mid-kernel.  Unknown compiler parameters
+  raise: a misspelt ``dimension_semantics`` must not vanish.
+- meshes: :func:`make_mesh` builds every concrete mesh with ``Auto`` axes,
+  which ``with_sharding_constraint`` under the repo's sharding rules needs
+  (jax 0.9 defaults ``jax.make_mesh`` to ``Explicit`` axes);
+  :func:`get_abstract_mesh` reads the mesh installed by ``jax.set_mesh``.
 """
 
 from __future__ import annotations
 
-import inspect
-from typing import Any, Dict, Optional, Sequence, Tuple
+from typing import Any, Optional, Sequence, Tuple
 
 import jax
+from jax.sharding import AxisType
 
-try:  # pallas is present in every pinned container; TPU lowering may not be
+try:  # the TPU lowering ships with jax; a stripped jaxlib may lack it
     from jax.experimental import pallas as pl  # noqa: F401
     from jax.experimental.pallas import tpu as _pltpu
 
@@ -56,25 +48,9 @@ def pallas_tpu():
     return _pltpu
 
 
-def _compiler_params_cls():
-    tpu = pallas_tpu()
-    cls = getattr(tpu, "CompilerParams", None)  # jax >= 0.6 name
-    if cls is None:
-        cls = getattr(tpu, "TPUCompilerParams", None)  # 0.4.x - 0.5 name
-    if cls is None:  # pragma: no cover - no known jax lacks both
-        raise AttributeError("no pallas TPU CompilerParams class found")
-    return cls
-
-
 def tpu_compiler_params(**kwargs) -> Any:
-    """``CompilerParams``/``TPUCompilerParams`` with unknown kwargs dropped.
-
-    Dropping (rather than raising) keeps kernels expressible against the
-    newest parameter set while still compiling on the pinned jax.
-    """
-    cls = _compiler_params_cls()
-    accepted = set(inspect.signature(cls).parameters)
-    return cls(**{k: v for k, v in kwargs.items() if k in accepted})
+    """``pltpu.CompilerParams(**kwargs)``; an unknown keyword raises."""
+    return pallas_tpu().CompilerParams(**kwargs)
 
 
 def vmem(shape: Tuple[int, ...], dtype) -> Any:
@@ -91,67 +67,18 @@ def prefetch_scalar_grid_spec(*, num_scalar_prefetch: int, grid, in_specs,
 
 
 # --------------------------------------------------------------------------
-# mesh lookups
+# meshes
 # --------------------------------------------------------------------------
+
+def make_mesh(axis_sizes: Sequence[int], axis_names: Sequence[str], *,
+              devices: Optional[Sequence[Any]] = None) -> Any:
+    """A concrete ``Mesh`` whose axes are all ``AxisType.Auto``."""
+    return jax.make_mesh(tuple(axis_sizes), tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(axis_names),
+                         devices=devices)
+
 
 def get_abstract_mesh() -> Optional[Any]:
-    """The mesh currently installed by a ``with mesh:`` context, or None.
-
-    On modern jax this is ``jax.sharding.get_abstract_mesh()``; on 0.4.x the
-    equivalent signal is the thread-local *physical* mesh.  Both expose
-    ``axis_names`` / ``shape``, which is all the sharding rules consume.
-    """
-    gam = getattr(jax.sharding, "get_abstract_mesh", None)
-    if gam is not None:
-        m = gam()
-        return None if m is None or m.empty else m
-    from jax._src import mesh as mesh_lib  # jax <= 0.4.x
-
-    env = getattr(mesh_lib, "thread_resources", None)
-    m = getattr(getattr(env, "env", None), "physical_mesh", None)
-    if m is None or m.empty:
-        return None
-    return m
-
-
-def set_mesh(mesh: Any):
-    """Context manager installing ``mesh`` for tracing/dispatch.
-
-    Modern jax spells this ``jax.set_mesh``; on 0.4.x the ``Mesh`` object is
-    itself the context manager and installs the thread-local physical mesh
-    that :func:`get_abstract_mesh` reads back.
-    """
-    sm = getattr(jax, "set_mesh", None)
-    if sm is not None:
-        return sm(mesh)
-    return mesh
-
-
-def make_abstract_mesh(axis_sizes: Sequence[int],
-                       axis_names: Sequence[str]) -> Any:
-    """``AbstractMesh(axis_sizes, axis_names)`` across the constructor skew."""
-    from jax.sharding import AbstractMesh
-
-    params = list(inspect.signature(AbstractMesh.__init__).parameters)
-    if "shape_tuple" in params:  # jax 0.4.x: one ((name, size), ...) tuple
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
-    return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
-
-
-# --------------------------------------------------------------------------
-# tree utils
-# --------------------------------------------------------------------------
-
-_tree = getattr(jax, "tree", jax.tree_util)
-
-
-def tree_map(f, tree, *rest, is_leaf=None):
-    return _tree.map(f, tree, *rest, is_leaf=is_leaf) \
-        if hasattr(_tree, "map") else \
-        jax.tree_util.tree_map(f, tree, *rest, is_leaf=is_leaf)
-
-
-def tree_leaves(tree, is_leaf=None):
-    if hasattr(_tree, "leaves"):
-        return _tree.leaves(tree, is_leaf=is_leaf)
-    return jax.tree_util.tree_leaves(tree, is_leaf=is_leaf)
+    """The mesh installed by ``jax.set_mesh``, or None outside one."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m is None or m.empty else m

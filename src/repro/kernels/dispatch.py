@@ -8,8 +8,9 @@ implementation of flash_attention/mamba_scan/ssd/rmsnorm runs here?" — and
 the answer depends on the detected backend, an env-var override, and (for
 the Pallas paths) launch parameters.  Centralizing it means:
 
-- CPU-only hosts (this container, CI) execute everything through the
-  reference or the Pallas interpreter without any call-site branching;
+- a TPU (v5e, with the installed jax 0.9.0 and libtpu) runs every Pallas
+  kernel compiled; CPU hosts (tests, CI) run the reference or the Pallas
+  interpreter, without any call-site branching;
 - kernel *launch parameters* (block sizes, chunk lengths) become first-class
   configuration options: :func:`launch_space` exposes them as a
   ``repro.core.spaces.ConfigSpace`` so CAMEO tunes them exactly like the
@@ -20,7 +21,8 @@ Modes
 -----
 ``ref`` | ``pallas`` | ``pallas_interpret``; the ``REPRO_KERNEL_MODE`` env
 var overrides, otherwise TPU backends get ``pallas`` and everything else
-gets ``ref``.
+gets ``ref``.  A TPU backend without the Pallas TPU lowering is an error,
+never a silent fall back to the reference.
 
 Precedence for launch parameters (highest first): an active tuned config
 installed via :func:`use_launch_config` (the tuner speaking — it must win so
@@ -69,9 +71,14 @@ def default_mode(backend: Optional[str] = None) -> str:
                 f"{KERNEL_MODE_ENV}={env!r} is not one of {MODES}")
         return env
     backend = backend or detect_backend()
-    if backend == "tpu" and compat.HAS_PALLAS_TPU:
-        return PALLAS
-    return REF
+    if backend != "tpu":
+        return REF
+    if not compat.HAS_PALLAS_TPU:
+        raise RuntimeError(
+            "the backend is a TPU but jax.experimental.pallas.tpu did not "
+            "import; set REPRO_KERNEL_MODE=ref to run the references on "
+            "purpose")
+    return PALLAS
 
 
 # --------------------------------------------------------------------------
@@ -245,11 +252,13 @@ def launch_params(family: str, **explicit: Any) -> Dict[str, Any]:
 
 @dataclass(frozen=True)
 class Resolution:
-    """Outcome of one dispatch decision."""
+    """Outcome of one dispatch decision.  ``backward`` marks the decision a
+    differentiated op makes for its backward pass (always the reference)."""
     family: str
     mode: str
     interpret: bool
     launch: Dict[str, Any] = field(default_factory=dict)
+    backward: bool = False
 
     @property
     def impl(self) -> Callable:
@@ -292,14 +301,15 @@ def _notify_recorders(res: Resolution) -> None:
         rec.append(res)
 
 
-def resolve(family: str, mode: Optional[str] = None,
-            **explicit: Any) -> Resolution:
+def resolve(family: str, mode: Optional[str] = None, *,
+            backward: bool = False, **explicit: Any) -> Resolution:
     mode = mode or default_mode()
     if mode not in MODES:
         raise ValueError(f"mode {mode!r} not one of {MODES}")
     res = Resolution(family=family, mode=mode,
                      interpret=(mode == PALLAS_INTERPRET),
-                     launch=launch_params(family, **explicit))
+                     launch=launch_params(family, **explicit),
+                     backward=backward)
     _notify_recorders(res)
     _notify_profiles(res)
     return res

@@ -12,8 +12,14 @@ TPU adaptation notes
   TPU idiom — grids are static).
 - GQA is handled in the index maps: the kv head index is ``q_head // group``,
   so no K/V replication ever materializes in HBM or VMEM.
+- Heads sit ahead of positions in every block, so each block's last two
+  dimensions are (positions, head_dim): the TPU tiling rule wants them
+  divisible by (8, 128) or equal to the array's own, and ``head_dim`` is
+  always whole.
 
-Layouts: q (B, Sq, Hq, D); k/v (B, Skv, Hkv, D); out (B, Sq, Hq, Dv).
+Layouts: prefill takes q (B, Sq, Hq, D), k/v (B, Skv, Hkv, D) and transposes
+them to heads-major inside the wrapper; decode reads the cache as it is
+stored, (B, Hkv, S, D), with q viewed as (B, Hkv, G, D).
 """
 
 from __future__ import annotations
@@ -66,9 +72,9 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
     @pl.when(visible)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32) * scale      # (Qb, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)              # (Kb, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)              # (Kb, Dv)
+        q = q_ref[0, 0].astype(jnp.float32) * scale            # (Qb, D)
+        k = k_ref[0, 0].astype(jnp.float32)                    # (Kb, D)
+        v = v_ref[0, 0].astype(jnp.float32)                    # (Kb, Dv)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)  # (Qb, Kb)
         if logit_softcap > 0.0:
@@ -100,7 +106,7 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
     def _finalize():
         l = l_ref[:, 0]
         denom = jnp.where(l == 0.0, 1.0, l)  # fully-masked rows -> 0 output
-        o_ref[0, :, 0, :] = (acc_ref[...] / denom[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / denom[:, None]).astype(o_ref.dtype)
 
 
 def flash_attention_pallas(
@@ -126,11 +132,11 @@ def flash_attention_pallas(
 
     q_block = max(8, min(q_block, sq))
     kv_block = max(8, min(kv_block, skv))
-    qp = _pad_to(q, 1, q_block)
-    kp = _pad_to(k, 1, kv_block)
-    vp = _pad_to(v, 1, kv_block)
-    n_q = qp.shape[1] // q_block
-    n_kv = kp.shape[1] // kv_block
+    qp = _pad_to(q.transpose(0, 2, 1, 3), 2, q_block)   # (B, Hq, Sq', D)
+    kp = _pad_to(k.transpose(0, 2, 1, 3), 2, kv_block)  # (B, Hkv, Skv', D)
+    vp = _pad_to(v.transpose(0, 2, 1, 3), 2, kv_block)
+    n_q = qp.shape[2] // q_block
+    n_kv = kp.shape[2] // kv_block
 
     kernel = functools.partial(
         _attn_kernel, scale=scale, causal=causal,
@@ -143,12 +149,12 @@ def flash_attention_pallas(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, q_block, 1, d), lambda ib, ih, iq, ikv: (ib, iq, ih, 0)),
-            pl.BlockSpec((1, kv_block, 1, d), lambda ib, ih, iq, ikv: (ib, ikv, ih // g, 0)),
-            pl.BlockSpec((1, kv_block, 1, dv), lambda ib, ih, iq, ikv: (ib, ikv, ih // g, 0)),
+            pl.BlockSpec((1, 1, q_block, d), lambda ib, ih, iq, ikv: (ib, ih, iq, 0)),
+            pl.BlockSpec((1, 1, kv_block, d), lambda ib, ih, iq, ikv: (ib, ih // g, ikv, 0)),
+            pl.BlockSpec((1, 1, kv_block, dv), lambda ib, ih, iq, ikv: (ib, ih // g, ikv, 0)),
         ],
-        out_specs=pl.BlockSpec((1, q_block, 1, dv), lambda ib, ih, iq, ikv: (ib, iq, ih, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, qp.shape[1], hq, dv), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, q_block, dv), lambda ib, ih, iq, ikv: (ib, ih, iq, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, hq, qp.shape[2], dv), q.dtype),
         scratch_shapes=[
             compat.vmem((q_block, dv), jnp.float32),
             compat.vmem((q_block, _LANE), jnp.float32),
@@ -158,7 +164,7 @@ def flash_attention_pallas(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qp, kp, vp)
-    return out[:, :sq]
+    return out[:, :, :sq].transpose(0, 2, 1, 3)
 
 
 # --------------------------------------------------------------------------
@@ -185,9 +191,9 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
 
     @pl.when(visible)
     def _compute():
-        q = q_ref[0, 0, :, :].astype(jnp.float32) * scale       # (G, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)               # (Kb, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)               # (Kb, Dv)
+        q = q_ref[0, 0].astype(jnp.float32) * scale             # (G, D)
+        k = k_ref[0, 0].astype(jnp.float32)                     # (Kb, D)
+        v = v_ref[0, 0].astype(jnp.float32)                     # (Kb, Dv)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)  # (G, Kb)
         if logit_softcap > 0.0:
@@ -215,13 +221,13 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
     def _finalize():
         l = l_ref[:, 0]
         denom = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0, :, :] = (acc_ref[...] / denom[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / denom[:, None]).astype(o_ref.dtype)
 
 
 def decode_attention_pallas(
     q: jax.Array,        # (B, 1, Hq, D)
-    k_cache: jax.Array,  # (B, Skv, Hkv, D)
-    v_cache: jax.Array,  # (B, Skv, Hkv, Dv)
+    k_cache: jax.Array,  # (B, Hkv, Skv, D)
+    v_cache: jax.Array,  # (B, Hkv, Skv, Dv)
     cache_len: jax.Array,  # (B,) int32 valid entries (incl. the new token)
     *,
     sliding_window: int = 0,
@@ -231,15 +237,15 @@ def decode_attention_pallas(
     interpret: bool = False,
 ) -> jax.Array:
     b, sq, hq, d = q.shape
-    _, skv, hkv, dv = v_cache.shape
+    _, hkv, skv, dv = v_cache.shape
     assert sq == 1
     g = hq // hkv
     if scale is None:
         scale = d ** -0.5
     kv_block = max(8, min(kv_block, skv))
-    kp = _pad_to(k_cache, 1, kv_block)
-    vp = _pad_to(v_cache, 1, kv_block)
-    n_kv = kp.shape[1] // kv_block
+    kp = _pad_to(k_cache, 2, kv_block)
+    vp = _pad_to(v_cache, 2, kv_block)
+    n_kv = kp.shape[2] // kv_block
 
     kernel = functools.partial(
         _decode_kernel, scale=scale, sliding_window=sliding_window,
@@ -249,11 +255,11 @@ def decode_attention_pallas(
         num_scalar_prefetch=1,
         grid=(b, hkv, n_kv),
         in_specs=[
-            pl.BlockSpec((1, 1, g, d), lambda ib, ih, ikv, len_ref: (ib, 0, ih, 0)),
-            pl.BlockSpec((1, kv_block, 1, d), lambda ib, ih, ikv, len_ref: (ib, ikv, ih, 0)),
-            pl.BlockSpec((1, kv_block, 1, dv), lambda ib, ih, ikv, len_ref: (ib, ikv, ih, 0)),
+            pl.BlockSpec((1, 1, g, d), lambda ib, ih, ikv, len_ref: (ib, ih, 0, 0)),
+            pl.BlockSpec((1, 1, kv_block, d), lambda ib, ih, ikv, len_ref: (ib, ih, ikv, 0)),
+            pl.BlockSpec((1, 1, kv_block, dv), lambda ib, ih, ikv, len_ref: (ib, ih, ikv, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, g, dv), lambda ib, ih, ikv, len_ref: (ib, 0, ih, 0)),
+        out_specs=pl.BlockSpec((1, 1, g, dv), lambda ib, ih, ikv, len_ref: (ib, ih, 0, 0)),
         scratch_shapes=[
             compat.vmem((g, dv), jnp.float32),
             compat.vmem((g, _LANE), jnp.float32),
@@ -263,9 +269,9 @@ def decode_attention_pallas(
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, 1, hq, dv), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, g, dv), q.dtype),
         compiler_params=compat.tpu_compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(cache_len.astype(jnp.int32), q, kp, vp)
-    return out
+    )(cache_len.astype(jnp.int32), q.reshape(b, hkv, g, d), kp, vp)
+    return out.reshape(b, 1, hq, dv)

@@ -135,8 +135,8 @@ def attention_blockwise_ref(
 
 def decode_attention_ref(
     q: jax.Array,      # (B, 1, Hq, D)
-    k_cache: jax.Array,  # (B, Skv, Hkv, D)
-    v_cache: jax.Array,  # (B, Skv, Hkv, Dv)
+    k_cache: jax.Array,  # (B, Hkv, Skv, D) — heads-major, as the cache is stored
+    v_cache: jax.Array,  # (B, Hkv, Skv, Dv)
     cache_len: jax.Array,  # (B,) int32 — number of valid entries incl. new one
     *,
     sliding_window: int = 0,
@@ -145,13 +145,13 @@ def decode_attention_ref(
 ) -> jax.Array:
     """Single-token decode attention over a (possibly ring) KV cache."""
     b, sq, hq, d = q.shape
-    _, skv, hkv, dv = v_cache.shape
+    _, hkv, skv, dv = v_cache.shape
     g = hq // hkv
     if scale is None:
         scale = d ** -0.5
     qf = q.astype(jnp.float32) * scale
     qg = qf.reshape(b, sq, hkv, g, d)
-    logits = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k_cache.astype(jnp.float32))
+    logits = jnp.einsum("bqhgd,bhkd->bhgqk", qg, k_cache.astype(jnp.float32))
     if logit_softcap > 0.0:
         logits = logit_softcap * jnp.tanh(logits / logit_softcap)
     k_pos = jnp.arange(skv)[None, :]
@@ -160,5 +160,5 @@ def decode_attention_ref(
         valid &= k_pos >= (cache_len[:, None] - sliding_window)
     logits = jnp.where(valid[:, None, None, None, :], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v_cache.astype(jnp.float32))
+    out = jnp.einsum("bhgqk,bhkd->bqhgd", probs, v_cache.astype(jnp.float32))
     return out.reshape(b, sq, hq, dv).astype(q.dtype)

@@ -13,7 +13,10 @@ Both the page table and the per-slot valid lengths ride in scalar prefetch
 (``num_scalar_prefetch=2``); unused table entries must hold valid pool
 indices (their rows are masked by ``cache_len``).
 
-Layouts: q (B, 1, Hq, D); pools (P, page_size, Hkv, D); out (B, 1, Hq, Dv).
+Layouts: q (B, 1, Hq, D), viewed as (B, Hkv, G, D) inside the wrapper;
+pools (P, Hkv, page_size, D), stored that way so every block's last two
+dimensions are (page_size, head_dim) — the TPU tiling rule's (8, 128)-or-whole
+— with no per-tick transpose; out (B, 1, Hq, Dv).
 """
 
 from __future__ import annotations
@@ -48,9 +51,9 @@ def _paged_decode_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(kv_start < cache_len)
     def _compute():
-        q = q_ref[0, 0, :, :].astype(jnp.float32) * scale        # (G, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)                # (ps, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)                # (ps, Dv)
+        q = q_ref[0, 0].astype(jnp.float32) * scale              # (G, D)
+        k = k_ref[0, 0].astype(jnp.float32)                      # (ps, D)
+        v = v_ref[0, 0].astype(jnp.float32)                      # (ps, Dv)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)  # (G, ps)
         if logit_softcap > 0.0:
@@ -77,13 +80,13 @@ def _paged_decode_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     def _finalize():
         l = l_ref[:, 0]
         denom = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0, :, :] = (acc_ref[...] / denom[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / denom[:, None]).astype(o_ref.dtype)
 
 
 def paged_decode_attention_pallas(
     q: jax.Array,           # (B, 1, Hq, D)
-    k_pages: jax.Array,     # (P, page_size, Hkv, D)
-    v_pages: jax.Array,     # (P, page_size, Hkv, Dv)
+    k_pages: jax.Array,     # (P, Hkv, page_size, D)
+    v_pages: jax.Array,     # (P, Hkv, page_size, Dv)
     page_table: jax.Array,  # (B, n_pages) int32 pool indices
     cache_len: jax.Array,   # (B,) int32 valid tokens (incl. the new one)
     *,
@@ -92,7 +95,7 @@ def paged_decode_attention_pallas(
     interpret: bool = False,
 ) -> jax.Array:
     b, sq, hq, d = q.shape
-    _, page_size, hkv, dv = v_pages.shape
+    _, hkv, page_size, dv = v_pages.shape
     assert sq == 1
     assert hq % hkv == 0, (hq, hkv)
     g = hq // hkv
@@ -109,14 +112,14 @@ def paged_decode_attention_pallas(
         grid=(b, hkv, n_pages),
         in_specs=[
             pl.BlockSpec((1, 1, g, d),
-                         lambda ib, ih, ip, tbl, lens: (ib, 0, ih, 0)),
-            pl.BlockSpec((1, page_size, 1, d),
-                         lambda ib, ih, ip, tbl, lens: (tbl[ib, ip], 0, ih, 0)),
-            pl.BlockSpec((1, page_size, 1, dv),
-                         lambda ib, ih, ip, tbl, lens: (tbl[ib, ip], 0, ih, 0)),
+                         lambda ib, ih, ip, tbl, lens: (ib, ih, 0, 0)),
+            pl.BlockSpec((1, 1, page_size, d),
+                         lambda ib, ih, ip, tbl, lens: (tbl[ib, ip], ih, 0, 0)),
+            pl.BlockSpec((1, 1, page_size, dv),
+                         lambda ib, ih, ip, tbl, lens: (tbl[ib, ip], ih, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, g, dv),
-                               lambda ib, ih, ip, tbl, lens: (ib, 0, ih, 0)),
+                               lambda ib, ih, ip, tbl, lens: (ib, ih, 0, 0)),
         scratch_shapes=[
             compat.vmem((g, dv), jnp.float32),
             compat.vmem((g, _LANE), jnp.float32),
@@ -126,10 +129,10 @@ def paged_decode_attention_pallas(
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, 1, hq, dv), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, g, dv), q.dtype),
         compiler_params=compat.tpu_compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(page_table.astype(jnp.int32), cache_len.astype(jnp.int32),
-      q, k_pages, v_pages)
-    return out
+      q.reshape(b, hkv, g, d), k_pages, v_pages)
+    return out.reshape(b, 1, hq, dv)

@@ -1,9 +1,9 @@
 """Pure-jnp oracle for paged decode attention.
 
-The paged layout stores K/V in a shared block pool of ``(pool_pages,
-page_size)`` rows; each batch slot owns a page table of pool indices.  Token
+The paged layout stores K/V in a shared block pool of ``(pool_pages, Hkv,
+page_size, D)``; each batch slot owns a page table of pool indices.  Token
 ``t`` of slot ``b`` lives in pool page ``page_table[b, t // page_size]`` at
-row ``t % page_size``.
+row ``t % page_size`` of every head.
 
 The oracle gathers the slot's pages back into a contiguous per-slot cache and
 runs the exact dense decode-attention math
@@ -30,17 +30,17 @@ from repro.kernels.flash_attention.ref import decode_attention_ref
 
 
 def gather_pages(pool: jax.Array, page_table: jax.Array) -> jax.Array:
-    """(P, ps, Hkv, D) pool + (B, n_pages) table -> (B, n_pages*ps, Hkv, D)."""
+    """(P, Hkv, ps, D) pool + (B, n_pages) table -> (B, Hkv, n_pages*ps, D)."""
     b, n_pages = page_table.shape
-    _, ps, hkv, d = pool.shape
-    gathered = pool[page_table]  # (B, n_pages, ps, Hkv, D)
-    return gathered.reshape(b, n_pages * ps, hkv, d)
+    _, hkv, ps, d = pool.shape
+    gathered = pool[page_table].transpose(0, 2, 1, 3, 4)  # (B, Hkv, n, ps, D)
+    return gathered.reshape(b, hkv, n_pages * ps, d)
 
 
 def paged_decode_attention_ref(
     q: jax.Array,           # (B, 1, Hq, D)
-    k_pages: jax.Array,     # (P, page_size, Hkv, D) shared pool
-    v_pages: jax.Array,     # (P, page_size, Hkv, Dv)
+    k_pages: jax.Array,     # (P, Hkv, page_size, D) shared pool
+    v_pages: jax.Array,     # (P, Hkv, page_size, Dv)
     page_table: jax.Array,  # (B, n_pages) int32 pool indices
     cache_len: jax.Array,   # (B,) int32 valid tokens (incl. the new one)
     *,

@@ -15,7 +15,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.configs.registry import input_specs
 from repro.launch.mesh import (
     batch_shardings, params_shardings, serve_shardings, state_shardings)
@@ -123,14 +122,13 @@ def _logits_sharding(logits_t, mesh: Mesh):
 def lower_step(run: RunConfig, mesh: Mesh):
     """jit + lower (no compile). Returns (bundle, lowered).
 
-    ``compat.set_mesh`` (``jax.set_mesh`` where it exists, the mesh's own
-    context manager on 0.4.x) so the active mesh is
-    visible during tracing — activation sharding constraints
-    (``sharding.specs.activation_sharding``) are no-ops otherwise and XLA
-    then replicates the layer-scan AD residuals across the batch axis.
+    ``jax.set_mesh`` so the active mesh is visible during tracing —
+    activation sharding constraints (``sharding.specs.activation_sharding``)
+    are no-ops otherwise and XLA then replicates the layer-scan AD residuals
+    across the batch axis.
     """
     b = build_step(run, mesh)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         jitted = jax.jit(b.fn, in_shardings=b.in_shardings,
                          out_shardings=b.out_shardings,
                          donate_argnums=b.donate_argnums)

@@ -1,4 +1,5 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=512 "
     + os.environ.get("XLA_FLAGS", ""))
@@ -6,9 +7,11 @@ os.environ["XLA_FLAGS"] = (
 """Multi-pod dry-run: lower + compile every (arch x shape) cell on the
 production meshes, with zero real allocation (ShapeDtypeStruct inputs).
 
-The two lines above MUST run before any other import (jax locks the device
-count on first init) — which is why this flag lives here and nowhere else;
-smoke tests and benches see 1 device.
+The lines above MUST run before any other import (jax picks its platform and
+locks the device count on first init) — which is why this flag lives here
+and nowhere else; smoke tests and benches see 1 device.  The dry run lowers
+on 512 virtual CPU devices, so it pins itself to the CPU: on a machine with
+a chip it must neither take the chip nor get the TPU backend.
 
 Usage:
     PYTHONPATH=src python -m repro.launch.dryrun --arch llama3.2-1b --shape train_4k

@@ -12,6 +12,7 @@ from typing import Any, Optional, Tuple
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro import compat
 from repro.sharding.specs import (
     batch_specs, cache_specs, param_specs, serve_state_specs,
     train_state_specs)
@@ -21,7 +22,7 @@ from repro.utils.config import MeshConfig, RunConfig
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return compat.make_mesh(shape, axes)
 
 
 def make_mesh(cfg: MeshConfig) -> Mesh:
@@ -31,7 +32,7 @@ def make_mesh(cfg: MeshConfig) -> Mesh:
         raise RuntimeError(
             f"mesh {cfg.shape} needs {n} devices, have {len(avail)} "
             "(dryrun.py sets --xla_force_host_platform_device_count=512)")
-    return jax.make_mesh(cfg.shape, cfg.axes, devices=avail[:n])
+    return compat.make_mesh(cfg.shape, cfg.axes, devices=avail[:n])
 
 
 def _as_named(tree_specs, mesh: Mesh):

@@ -41,6 +41,7 @@ from repro.launch.tune import (measure_backend_arg, tune_launch_config,
 from repro.models.model import build_model
 from repro.obs import trace as obs_trace
 from repro.train.serve_step import jitted_steps, sample_token
+from repro.utils.compile_cache import enable_compile_cache
 from repro.utils.config import MeshConfig, RunConfig, ShapeConfig
 
 
@@ -155,6 +156,7 @@ def main() -> int:
                          "— inspect with `python -m repro.obs.report PATH` "
                          "or chrome://tracing / Perfetto")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.trace_out:
         with obs_trace.trace_to(args.trace_out):

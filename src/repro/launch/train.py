@@ -22,7 +22,6 @@ import argparse
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.checkpoint.manager import CheckpointManager
 from repro.configs.registry import get_smoke_config, get_model_config, list_archs
 from repro.data.pipeline import make_data
@@ -33,6 +32,7 @@ from repro.runtime.driver import TrainDriver
 from repro.runtime.elastic import adjust_run_for_devices
 from repro.train.optimizer import make_optimizer
 from repro.train.train_step import init_train_state, make_train_step
+from repro.utils.compile_cache import enable_compile_cache
 from repro.utils.config import (MeshConfig, ParallelConfig, RunConfig,
                                 ShapeConfig, TrainConfig)
 from repro.utils.logging import MetricsLogger
@@ -60,6 +60,7 @@ def main() -> int:
                     help="measurements per ask/tell tuning round for "
                          "--tune-launch (1 = sequential)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = (get_model_config(args.arch) if args.full_config
            else get_smoke_config(args.arch))
@@ -92,7 +93,7 @@ def main() -> int:
         return init_train_state(model, run, optimizer,
                                 jax.random.PRNGKey(run.train.seed))
 
-    with compat.set_mesh(mesh), \
+    with jax.set_mesh(mesh), \
             MetricsLogger(name=f"train-{args.arch}") as logger:
         state_t = jax.eval_shape(init_state)
         step_fn = jax.jit(

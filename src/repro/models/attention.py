@@ -19,7 +19,8 @@ from repro.utils.config import ModelConfig, ParallelConfig
 
 
 class KVCache(NamedTuple):
-    """Ring-free append cache. k/v: (B, S_max, H_kv, D); length: (B,) int32."""
+    """Append (or sliding-window ring) cache, stored heads-major as the decode
+    kernel reads it: k/v (B, H_kv, S_max, D); length: (B,) int32."""
     k: jax.Array
     v: jax.Array
     length: jax.Array
@@ -28,8 +29,8 @@ class KVCache(NamedTuple):
 class PagedKVCache(NamedTuple):
     """Block-paged KV cache over a shared page pool.
 
-    ``k_pages``/``v_pages``: ``(P, page_size, H_kv, D)`` — the pool, shared by
-    every slot of the batch.  ``page_table``: ``(B, pages_per_slot_max)``
+    ``k_pages``/``v_pages``: ``(P, H_kv, page_size, D)`` — the pool, shared by
+    every slot of the batch, stored in the layout the paged kernel reads.  ``page_table``: ``(B, pages_per_slot_max)``
     int32 — token ``t`` of slot ``b`` lives at pool page
     ``page_table[b, t // page_size]``, row ``t % page_size``.  Unused table
     entries must still hold *valid* pool indices (the attention mask from
@@ -91,12 +92,13 @@ def apply_gqa(
                 "paged KV cache does not support sliding-window attention "
                 "(the ring layout and the page layout disagree about where "
                 "token t lives); serve sliding-window models dense")
-        ps = cache.k_pages.shape[1]
+        ps = cache.k_pages.shape[2]
         rows = jnp.arange(b)
         page_ids = cache.page_table[rows, cache.length // ps]  # (B,)
         row_ids = cache.length % ps                            # (B,)
-        k_pages = cache.k_pages.at[page_ids, row_ids].set(k[:, 0])
-        v_pages = cache.v_pages.at[page_ids, row_ids].set(v[:, 0])
+        # (B,) page x (B,) row around the head slice: a (B, Hkv, D) write
+        k_pages = cache.k_pages.at[page_ids, :, row_ids].set(k[:, 0])
+        v_pages = cache.v_pages.at[page_ids, :, row_ids].set(v[:, 0])
         new_len = cache.length + 1
         o = ops.paged_decode_attention(
             q, k_pages, v_pages, cache.page_table, new_len,
@@ -104,7 +106,7 @@ def apply_gqa(
         new_cache = PagedKVCache(k_pages, v_pages, cache.page_table, new_len)
     elif decode:
         assert cache is not None and s == 1
-        size = cache.k.shape[1]
+        size = cache.k.shape[2]
         ring = cfg.sliding_window > 0 and size <= cfg.sliding_window
         idx = cache.length % size if ring else cache.length  # (B,)
         k_cache = _scatter_time(cache.k, k, idx)
@@ -132,27 +134,29 @@ def apply_gqa(
             logit_softcap=cfg.attn_logit_softcap,
             q_block=par.attn_q_block, kv_block=par.attn_kv_block)
         new_cache = None
-        if cache is not None:  # prefill into cache
-            size = cache.k.shape[1]
+        if cache is not None:  # prefill into the heads-major cache
+            size = cache.k.shape[2]
+            kt, vt = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
             if s <= size:
-                k_cache = jax.lax.dynamic_update_slice(cache.k, k, (0, 0, 0, 0))
-                v_cache = jax.lax.dynamic_update_slice(cache.v, v, (0, 0, 0, 0))
+                k_cache = jax.lax.dynamic_update_slice(cache.k, kt, (0, 0, 0, 0))
+                v_cache = jax.lax.dynamic_update_slice(cache.v, vt, (0, 0, 0, 0))
             else:
                 # ring cache smaller than the prompt (sliding window): pack
                 # the last `size` keys at their ring slots (pos % size)
                 j = jnp.arange(size)
                 tok = s - size + ((j - s) % size)
-                k_cache, v_cache = k[:, tok], v[:, tok]
+                k_cache, v_cache = kt[:, :, tok], vt[:, :, tok]
             new_cache = KVCache(k_cache, v_cache, cache.length + s)
     out = jnp.einsum("bse,ed->bsd", o.reshape(b, s, cfg.num_heads * hd), p["wo"])
     return out, new_cache
 
 
 def _scatter_time(cache: jax.Array, new: jax.Array, idx: jax.Array) -> jax.Array:
-    """Write `new` (B, 1, H, D) at per-batch time index `idx` (B,)."""
-    b = cache.shape[0]
-    onehot = jax.nn.one_hot(idx, cache.shape[1], dtype=cache.dtype)  # (B, S)
-    return cache * (1 - onehot)[:, :, None, None] + onehot[:, :, None, None] * new
+    """Write `new` (B, 1, H, D) at per-batch time index `idx` (B,) of a
+    heads-major (B, H, S, D) cache."""
+    onehot = jax.nn.one_hot(idx, cache.shape[2], dtype=cache.dtype)  # (B, S)
+    oh = onehot[:, None, :, None]
+    return cache * (1 - oh) + oh * new.transpose(0, 2, 1, 3)
 
 
 def init_paged_kv_cache(cfg: ModelConfig, batch: int, pool_pages: int,
@@ -169,9 +173,9 @@ def init_paged_kv_cache(cfg: ModelConfig, batch: int, pool_pages: int,
         raise NotImplementedError(
             "paged KV cache does not support sliding-window attention")
     return PagedKVCache(
-        k_pages=jnp.zeros((pool_pages + 1, page_size, cfg.num_kv_heads, hd),
+        k_pages=jnp.zeros((pool_pages + 1, cfg.num_kv_heads, page_size, hd),
                           dtype),
-        v_pages=jnp.zeros((pool_pages + 1, page_size, cfg.num_kv_heads, hd),
+        v_pages=jnp.zeros((pool_pages + 1, cfg.num_kv_heads, page_size, hd),
                           dtype),
         page_table=jnp.full((batch, pages_per_slot_max), pool_pages,
                             jnp.int32),
@@ -185,8 +189,8 @@ def init_kv_cache(cfg: ModelConfig, batch: int, max_len: int, dtype) -> KVCache:
         # ring buffer: the cache never needs to exceed the attention window
         max_len = min(max_len, cfg.sliding_window)
     return KVCache(
-        k=jnp.zeros((batch, max_len, cfg.num_kv_heads, hd), dtype),
-        v=jnp.zeros((batch, max_len, cfg.num_kv_heads, hd), dtype),
+        k=jnp.zeros((batch, cfg.num_kv_heads, max_len, hd), dtype),
+        v=jnp.zeros((batch, cfg.num_kv_heads, max_len, hd), dtype),
         length=jnp.zeros((batch,), jnp.int32),
     )
 

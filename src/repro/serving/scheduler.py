@@ -115,7 +115,8 @@ def _scatter_paged_rows(dst_tree, src_tree, slot: int, pages: List[int],
                         scratch_page: int):
     """Write a dense batch-1 prefill state into slot ``slot`` of a paged
     decode state: KV rows land in the slot's reserved pool ``pages`` (the
-    first ``len(pages) * page_size`` dense rows, page-reshaped), the page
+    first ``len(pages) * page_size`` dense rows, page-reshaped into the
+    pool's (page, head, row) layout once, at admission), the page
     table row is rewritten wholesale (tail entries pinned to the scratch
     page — valid and owned by nobody), and recurrent (SSM) leaves scatter
     exactly like the dense path."""
@@ -127,13 +128,15 @@ def _scatter_paged_rows(dst_tree, src_tree, slot: int, pages: List[int],
     def one(dst, src):
         if isinstance(dst, PagedKVCache):
             n = len(pages)
-            nsb = src.k.shape[0]
-            rows = src.k[:, 0, :n * page_size]
-            rows = rows.reshape(nsb, n, page_size, *rows.shape[2:])
-            k_pages = dst.k_pages.at[:, pages_arr].set(rows)
-            rows = src.v[:, 0, :n * page_size]
-            rows = rows.reshape(nsb, n, page_size, *rows.shape[2:])
-            v_pages = dst.v_pages.at[:, pages_arr].set(rows)
+
+            def paged(dense):  # (nsb, 1, Hkv, S, D) -> (nsb, n, Hkv, ps, D)
+                nsb, _, hkv, _, d = dense.shape
+                rows = dense[:, 0, :, :n * page_size]
+                return rows.reshape(nsb, hkv, n, page_size, d).transpose(
+                    0, 2, 1, 3, 4)
+
+            k_pages = dst.k_pages.at[:, pages_arr].set(paged(src.k))
+            v_pages = dst.v_pages.at[:, pages_arr].set(paged(src.v))
             table = dst.page_table.at[:, slot].set(table_row[None])
             length = dst.length.at[:, slot].set(src.length[:, 0])
             return PagedKVCache(k_pages, v_pages, table, length)

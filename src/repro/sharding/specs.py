@@ -35,7 +35,6 @@ def data_axes_of(mesh_axes: Tuple[str, ...]) -> Tuple[str, ...]:
 
 
 def _active_mesh() -> Optional[Mesh]:
-    # version-gated lookup (jax.sharding.get_abstract_mesh is 0.5+)
     return compat.get_abstract_mesh()
 
 
@@ -172,8 +171,8 @@ def param_specs(params_shapes, cfg: ModelConfig, par: ParallelConfig,
 def named_shardings(params_shapes, cfg: ModelConfig, par: ParallelConfig,
                     mesh: Mesh) -> Any:
     specs = param_specs(params_shapes, cfg, par, mesh)
-    return compat.tree_map(lambda s: NamedSharding(mesh, s), specs,
-                           is_leaf=lambda x: isinstance(x, P))
+    return jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
+                        is_leaf=lambda x: isinstance(x, P))
 
 
 def cache_specs(state_shapes, cfg: ModelConfig, par: ParallelConfig,
@@ -283,7 +282,7 @@ def batch_specs(batch_template, mesh: Mesh):
             return P(daxes, *([None] * (len(leaf.shape) - 1)))
         return P(*([None] * len(leaf.shape)))
 
-    return compat.tree_map(one, batch_template)
+    return jax.tree.map(one, batch_template)
 
 
 def serve_state_specs(state_template, cfg: ModelConfig, par: ParallelConfig,
@@ -306,5 +305,5 @@ def serve_state_specs(state_template, cfg: ModelConfig, par: ParallelConfig,
             out[-1] = "model"
         return P(*out)
 
-    extras = compat.tree_map(extra_spec, state_template.extras)
+    extras = jax.tree.map(extra_spec, state_template.extras)
     return type(state_template)(caches=caches, lengths=lengths, extras=extras)

@@ -124,8 +124,9 @@ def make_decode_step(model: Model, run: RunConfig,
 
 @functools.lru_cache(maxsize=32)
 def _jitted_steps_cached(model: Model, run: RunConfig,
-                         cache_len: Optional[int],
-                         frozen_launch: Tuple) -> Tuple[Callable, Callable]:
+                         cache_len: Optional[int], frozen_launch: Tuple,
+                         kernel_mode: str) -> Tuple[Callable, Callable]:
+    del kernel_mode  # a key only: the mode is read again at trace time
     launch_config = {f: dict(p) for f, p in frozen_launch}
     return (jax.jit(make_prefill_step(model, run, cache_len=cache_len,
                                       launch_config=launch_config)),
@@ -139,20 +140,21 @@ def jitted_steps(model: Model, run: RunConfig,
                  ) -> Tuple[Callable, Callable]:
     """Cached jit-compiled ``(prefill, decode)`` for this serving setup.
 
-    Keyed on (model, run, cache_len, canonical launch config) — ``Model`` is
-    a NamedTuple of config + closures, hashable by identity of those
-    closures — so repeated :func:`generate` calls and serving loops reuse
-    compilations instead of retracing, while a *different* tuned launch
-    config correctly gets a fresh trace (launch params are baked at trace
-    time).  LRU-bounded so long-lived processes cycling through many models
-    do not pin every compilation.
+    Keyed on (model, run, cache_len, canonical launch config, kernel mode)
+    — ``Model`` is a NamedTuple of config + closures, hashable by identity
+    of those closures — so repeated :func:`generate` calls and serving loops
+    reuse compilations instead of retracing, while a *different* tuned
+    launch config or kernel mode (``REPRO_KERNEL_MODE``) correctly gets a
+    fresh trace (both are baked in at trace time).  LRU-bounded so
+    long-lived processes cycling through many models do not pin every
+    compilation.
     """
+    key = (model, run, cache_len, freeze_launch_config(launch_config),
+           dispatch.default_mode())
     if not obs_trace.enabled():
-        return _jitted_steps_cached(model, run, cache_len,
-                                    freeze_launch_config(launch_config))
+        return _jitted_steps_cached(*key)
     before = _jitted_steps_cached.cache_info()
-    steps = _jitted_steps_cached(model, run, cache_len,
-                                 freeze_launch_config(launch_config))
+    steps = _jitted_steps_cached(*key)
     after = _jitted_steps_cached.cache_info()
     hit = after.hits > before.hits
     obs_metrics.REGISTRY.inc(

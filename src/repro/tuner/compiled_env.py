@@ -9,6 +9,11 @@ the roofline terms as system-event counters.
 This is exactly the paper's "production environment is expensive to query"
 setting: one intervention costs a full XLA compile (tens of seconds), which
 is why CAMEO warm-starts from the cheap AnalyticTPUEnv source.
+
+The child runs on the CPU (``JAX_PLATFORMS=cpu``), never on a chip the
+parent may hold.  A child that fails on a sharding or divisibility error
+marks the configuration infeasible (``inf``); any other failure is a crash
+and raises, instead of passing for an infeasible configuration.
 """
 
 from __future__ import annotations
@@ -28,6 +33,21 @@ from repro.tuner.space import config_to_parallel_kv, framework_space
 from repro.utils.hardware import TPU_V5E, HardwareSpec
 
 _REPO_SRC = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", ".."))
+
+# what a dry run that failed because the configuration cannot be laid out on
+# the mesh prints; anything else in a failed child is a crash
+_INFEASIBLE_MARKERS = ("divisible", "sharding", "Sharding", "mesh",
+                       "RESOURCE_EXHAUSTED")
+
+
+class DryRunCrash(RuntimeError):
+    """The dry-run child failed for a reason other than an infeasible
+    configuration."""
+
+
+def _failure_is_infeasible(stderr: str) -> bool:
+    lines = [ln for ln in stderr.strip().splitlines() if ln.strip()]
+    return bool(lines) and any(m in lines[-1] for m in _INFEASIBLE_MARKERS)
 
 
 def make_aligned_source(arch: str = "llama3.2-1b", seed: int = 0):
@@ -113,14 +133,18 @@ class CompiledPerfEnv(PooledEnv):
                 cmd += ["--parallel", kv]
             if self.multi_pod:
                 cmd += ["--multi-pod"]
-            env = dict(os.environ, PYTHONPATH=_REPO_SRC)
+            env = dict(os.environ, PYTHONPATH=_REPO_SRC, JAX_PLATFORMS="cpu")
             try:
                 proc = subprocess.run(cmd, capture_output=True, text=True,
                                       timeout=self.timeout_s, env=env)
             except subprocess.TimeoutExpired:
                 return {n: 0.0 for n in self.counter_names}, float("inf")
             if proc.returncode != 0:
-                # invalid configuration (sharding/divisibility): infeasible
+                if not _failure_is_infeasible(proc.stderr):
+                    raise DryRunCrash(
+                        f"dry run of {self.arch} x {self.shape_name} "
+                        f"[{kv or 'default'}] crashed (exit "
+                        f"{proc.returncode}):\n{proc.stderr[-4000:]}")
                 return {n: 0.0 for n in self.counter_names}, float("inf")
             art = os.path.join(_REPO_SRC, "..", "artifacts", "dryrun",
                                f"{self.arch}__{self.shape_name}__"

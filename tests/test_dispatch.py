@@ -73,7 +73,7 @@ def test_generic_dispatch_attention_and_decode_variant():
     np.testing.assert_allclose(out, ref, atol=2e-5, rtol=1e-4)
 
     qd = rand(2, 1, 8, 32)
-    kc, vc = rand(2, 80, 2, 32), rand(2, 80, 2, 32)
+    kc, vc = rand(2, 2, 80, 32), rand(2, 2, 80, 32)  # heads-major cache
     clen = jnp.asarray([13, 77], jnp.int32)
     refd = aref.decode_attention_ref(qd, kc, vc, clen)
     # ref mode drops the kv_block launch param (the oracle has no blocking)

@@ -80,7 +80,7 @@ def test_blockwise_ref_matches_plain():
 def test_decode_attention_matches_oracle(sw):
     b, skv, hq, hkv, d = 2, 80, 8, 2, 32
     q = rand(b, 1, hq, d)
-    kc, vc = rand(b, skv, hkv, d), rand(b, skv, hkv, d)
+    kc, vc = rand(b, hkv, skv, d), rand(b, hkv, skv, d)  # heads-major
     clen = jnp.asarray([13, 77], jnp.int32)
     ref = aref.decode_attention_ref(q, kc, vc, clen, sliding_window=sw)
     out = decode_attention_pallas(q, kc, vc, clen, sliding_window=sw,

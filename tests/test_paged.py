@@ -31,21 +31,21 @@ def rand(*shape):
 
 
 def _paged_layout(k_cache, v_cache, page_size, perm=None):
-    """Scatter a dense (B, L, Hkv, D) cache into a paged pool.  ``perm``
-    shuffles which pool page holds which logical page (identity when None),
-    so tests cover non-contiguous page tables."""
-    b, l, hkv, d = k_cache.shape
+    """Scatter a dense (B, Hkv, L, D) cache into a (P, Hkv, page_size, D)
+    paged pool.  ``perm`` shuffles which pool page holds which logical page
+    (identity when None), so tests cover non-contiguous page tables."""
+    b, hkv, l, d = k_cache.shape
     assert l % page_size == 0
     n_pages = l // page_size
     order = np.arange(b * n_pages) if perm is None else np.asarray(perm)
-    k_pages = np.zeros((b * n_pages, page_size, hkv, d), np.float32)
+    k_pages = np.zeros((b * n_pages, hkv, page_size, d), np.float32)
     v_pages = np.zeros_like(k_pages)
     table = np.zeros((b, n_pages), np.int32)
     for bi in range(b):
         for p in range(n_pages):
             pid = int(order[bi * n_pages + p])
-            k_pages[pid] = k_cache[bi, p * page_size:(p + 1) * page_size]
-            v_pages[pid] = v_cache[bi, p * page_size:(p + 1) * page_size]
+            k_pages[pid] = k_cache[bi, :, p * page_size:(p + 1) * page_size]
+            v_pages[pid] = v_cache[bi, :, p * page_size:(p + 1) * page_size]
             table[bi, p] = pid
     return jnp.asarray(k_pages), jnp.asarray(v_pages), jnp.asarray(table)
 
@@ -60,7 +60,7 @@ def test_single_full_page_is_bit_identical_to_dense():
     # decode reference bit-for-bit — not approximately
     b, l, hq, hkv, d = 3, 16, 4, 2, 8
     q = rand(b, 1, hq, d)
-    k_cache, v_cache = rand(b, l, hkv, d), rand(b, l, hkv, d)
+    k_cache, v_cache = rand(b, hkv, l, d), rand(b, hkv, l, d)
     lens = jnp.asarray([5, 16, 1], jnp.int32)
     k_pages, v_pages, table = _paged_layout(np.asarray(k_cache),
                                             np.asarray(v_cache), page_size=l)
@@ -72,7 +72,7 @@ def test_single_full_page_is_bit_identical_to_dense():
 def test_permuted_multi_page_pool_is_bit_identical_to_dense():
     b, l, ps, hq, hkv, d = 2, 32, 8, 4, 2, 8
     q = rand(b, 1, hq, d)
-    k_cache, v_cache = rand(b, l, hkv, d), rand(b, l, hkv, d)
+    k_cache, v_cache = rand(b, hkv, l, d), rand(b, hkv, l, d)
     lens = jnp.asarray([19, 32], jnp.int32)
     perm = np.random.default_rng(3).permutation(b * (l // ps))
     k_pages, v_pages, table = _paged_layout(
@@ -89,7 +89,7 @@ def test_permuted_multi_page_pool_is_bit_identical_to_dense():
 def test_pallas_interpret_matches_ref():
     b, l, ps, hq, hkv, d = 2, 32, 8, 4, 2, 16
     q = rand(b, 1, hq, d)
-    k_cache, v_cache = rand(b, l, hkv, d), rand(b, l, hkv, d)
+    k_cache, v_cache = rand(b, hkv, l, d), rand(b, hkv, l, d)
     lens = jnp.asarray([13, 27], jnp.int32)
     perm = np.random.default_rng(5).permutation(b * (l // ps))
     k_pages, v_pages, table = _paged_layout(

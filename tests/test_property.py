@@ -165,18 +165,18 @@ def test_paged_decode_bit_identical_to_dense(b, n_pages, seed):
     ps, hq, hkv, d = 4, 4, 2, 8
     l = n_pages * ps
     q = jnp.asarray(rng.normal(size=(b, 1, hq, d)).astype(np.float32))
-    k = rng.normal(size=(b, l, hkv, d)).astype(np.float32)
-    v = rng.normal(size=(b, l, hkv, d)).astype(np.float32)
+    k = rng.normal(size=(b, hkv, l, d)).astype(np.float32)  # heads-major
+    v = rng.normal(size=(b, hkv, l, d)).astype(np.float32)
     lens = jnp.asarray(rng.integers(1, l + 1, size=b), jnp.int32)
     perm = rng.permutation(b * n_pages)
-    k_pages = np.zeros((b * n_pages, ps, hkv, d), np.float32)
+    k_pages = np.zeros((b * n_pages, hkv, ps, d), np.float32)
     v_pages = np.zeros_like(k_pages)
     table = np.zeros((b, n_pages), np.int32)
     for bi in range(b):
         for p in range(n_pages):
             pid = int(perm[bi * n_pages + p])
-            k_pages[pid] = k[bi, p * ps:(p + 1) * ps]
-            v_pages[pid] = v[bi, p * ps:(p + 1) * ps]
+            k_pages[pid] = k[bi, :, p * ps:(p + 1) * ps]
+            v_pages[pid] = v[bi, :, p * ps:(p + 1) * ps]
             table[bi, p] = pid
     out = pref.paged_decode_attention_ref(
         q, jnp.asarray(k_pages), jnp.asarray(v_pages), jnp.asarray(table),

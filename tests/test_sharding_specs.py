@@ -5,10 +5,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from conftest import tiny_model_config
-from repro.compat import make_abstract_mesh
 from repro.models.model import build_model
 from repro.sharding.specs import (batch_specs, cache_specs, param_specs,
                                   train_state_specs)
@@ -17,8 +16,8 @@ from repro.train.train_step import init_train_state
 from repro.utils.config import (MeshConfig, ParallelConfig, RunConfig,
                                 ShapeConfig, TrainConfig)
 
-MESH = make_abstract_mesh((16, 16), ("data", "model"))
-MESH_MP = make_abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH_MP = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 PAR = ParallelConfig(fsdp=2, tp=16)
 
 
