@@ -77,7 +77,8 @@ def main():
     stats = obs_report.span_stats(events)
     print(f"\nwinning config replayed at {y_win:.1f} ms wall; "
           f"lifecycle breakdown:")
-    for name in ("queue", "prefill", "prefill_chunk", "decode_tick"):
+    for name in ("serve.queue", "serve.prefill", "prefill_chunk",
+                 "serve.decode"):
         s = stats.get(name)
         if s is None:
             continue

@@ -376,8 +376,8 @@ def profile_dispatches():
     :class:`DispatchProfile` accumulating per-family resolution counts and
     the wall time spent inside dispatched implementations.  When the obs
     tracer is active, each dispatched call additionally exports a span on
-    the kernel track and bumps the ``dispatch_wall_s`` /
-    ``dispatch_resolutions_total`` registry instruments."""
+    the kernel track and bumps the ``dispatch_resolutions_total`` registry
+    instrument."""
     prof = DispatchProfile()
     with _PROFILES_LOCK:
         _PROFILES.append(prof)
@@ -427,8 +427,6 @@ def dispatch(family: str, *args: Any, mode: Optional[str] = None,
             p._timed(family, res.mode, dt)
     if obs_trace.enabled():
         obs_metrics.REGISTRY.inc("dispatch_resolutions_total",
-                                 family=family, mode=res.mode)
-        obs_metrics.REGISTRY.inc("dispatch_wall_s", dt,
                                  family=family, mode=res.mode)
     return out
 

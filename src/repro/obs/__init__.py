@@ -2,7 +2,14 @@
 and trace reporting.
 
 - :mod:`repro.obs.trace` — nested span tracer with Chrome trace-event /
-  Perfetto JSON export; zero-cost (and bit-identical) when disabled.
+  Perfetto JSON export; zero-cost (and bit-identical) when disabled.  Its
+  wall spans are mirrored as ``jax.profiler.TraceAnnotation``s of the same
+  name, so a profiler session shows them on the device trace's clock.  The batcher
+  spans every tick as ``serve.tick`` ⊃ ``serve.admit`` (⊃ ``serve.prefill``,
+  ``serve.scatter``, ``serve.first_token``), ``serve.decode``,
+  ``serve.sample`` and ``serve.feedback``, with ``serve.queue`` from
+  submit to admission; ``serve.tick`` and ``serve.admit`` carry ``syncs``,
+  the times the host waited on the device.
 - :mod:`repro.obs.metrics` — the metrics registry that is the single
   source of truth for discovery-variable names, plus labeled runtime
   instruments.

@@ -23,7 +23,8 @@ from typing import Any, Dict, Iterable, List, Mapping, Optional, Tuple
 
 # phases we emit (a subset of the Chrome trace-event vocabulary)
 _KNOWN_PHASES = {"X", "i", "I", "C", "b", "e", "n", "B", "E", "M", "s", "t", "f"}
-_LIFECYCLE_SPANS = ("queue", "prefill", "prefill_chunk", "decode_tick")
+_LIFECYCLE_SPANS = ("serve.queue", "serve.prefill", "prefill_chunk",
+                    "serve.decode")
 
 
 def validate_trace_doc(doc: Any) -> List[Dict[str, Any]]:

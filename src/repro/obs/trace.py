@@ -19,6 +19,12 @@ Design constraints, in order:
    discrete-event simulator emits spans at *modeled* microseconds on its own
    process track (:data:`TRACK_SIM`), so one trace file holds both the real
    and the modeled view of a serving run.
+4. **One vocabulary on the profiler's clock too.**  Every context-managed
+   wall span (:meth:`Tracer.span`) also opens a
+   ``jax.profiler.TraceAnnotation`` of the same name, so while a profiler
+   session runs the span lands in the ``.xplane.pb`` host plane beside the
+   device's ops.  Modeled-time spans (:meth:`Tracer.complete`) are not
+   mirrored, and with no tracer active no annotation is made.
 
 Tracks are logical Chrome "processes" (integer pids with name metadata):
 serving wall time, simulator modeled time, tuner rounds, kernel dispatch,
@@ -32,6 +38,8 @@ import threading
 import time
 from contextlib import contextmanager
 from typing import Any, Dict, Iterator, List, Optional, Tuple
+
+import jax.profiler
 
 #: logical process ids of the exported trace (named via "M" metadata events)
 TRACK_SERVE = 1     # real batcher / replay wall time
@@ -68,9 +76,10 @@ NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """One live complete-event span; records duration on ``__exit__``."""
+    """One live complete-event span; records duration on ``__exit__``, and
+    holds the profiler annotation of the same name while it is open."""
 
-    __slots__ = ("_tracer", "name", "cat", "track", "args", "_t0")
+    __slots__ = ("_tracer", "name", "cat", "track", "args", "_t0", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, track: int,
                  args: Dict[str, Any]):
@@ -80,6 +89,7 @@ class _Span:
         self.track = track
         self.args = args
         self._t0 = 0.0
+        self._ann = None
 
     def set(self, **args: Any) -> "_Span":
         """Attach (or overwrite) args on the open span."""
@@ -87,14 +97,17 @@ class _Span:
         return self
 
     def __enter__(self) -> "_Span":
+        self._ann = jax.profiler.TraceAnnotation(self.name)
+        self._ann.__enter__()
         self._t0 = self._tracer.now_us()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        t1 = self._tracer.now_us()
+        self._ann.__exit__(exc_type, exc, tb)
         if exc_type is not None:
             self.args["error"] = exc_type.__name__
-        self._tracer.complete(self.name, self._t0,
-                              self._tracer.now_us() - self._t0,
+        self._tracer.complete(self.name, self._t0, t1 - self._t0,
                               cat=self.cat, track=self.track, **self.args)
         return False
 
@@ -145,7 +158,8 @@ class Tracer:
 
     def span(self, name: str, *, cat: str = "span",
              track: int = TRACK_SERVE, **args: Any) -> _Span:
-        """A context-managed wall-clock span."""
+        """A context-managed wall-clock span, mirrored as a profiler
+        annotation of the same name while it is open."""
         return _Span(self, name, cat, track, dict(args))
 
     def instant(self, name: str, *, cat: str = "event",

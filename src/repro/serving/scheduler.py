@@ -264,56 +264,72 @@ class ContinuousBatcher:
         return [i for i, s in enumerate(self._slots) if s is None]
 
     def _prefill_and_seat(self, req: Request, slot: int,
-                          pages: Optional[List[int]]) -> None:
+                          pages: Optional[List[int]]) -> int:
         """Run the (dense, batch-1) prefill and seat the request in ``slot``
-        — scattered into its reserved ``pages`` for paged deployments."""
+        — scattered into its reserved ``pages`` for paged deployments.
+        Returns the number of times the host waited on the device."""
         tr = obs_trace.active()
         if tr is not None:
             # admission closes the queue phase begun at submit
             sub_ts = self._submit_ts.pop(req.uid, None)
             if sub_ts is not None:
-                tr.complete("queue", sub_ts, tr.now_us() - sub_ts,
+                tr.complete("serve.queue", sub_ts, tr.now_us() - sub_ts,
                             cat="request", uid=req.uid)
-            tr.instant("admit", cat="request", uid=req.uid, slot=slot,
-                       pages=len(pages) if pages is not None else 0)
-        prompt = jnp.asarray(req.prompt, jnp.int32)[None, :]
-        batch = {"tokens": prompt}
-        for k, v in req.extras.items():
-            batch[k] = jnp.asarray(v)[None]
-        t0 = time.perf_counter()
-        with obs_trace.span("prefill", cat="request", uid=req.uid,
-                            prompt_len=len(req.prompt)):
-            one_state, logits = self._prefill(self.params, batch)
-            jax.block_until_ready(logits)
-        self.prefill_s += time.perf_counter() - t0
-        if pages is not None:
-            caches = _scatter_paged_rows(
-                self.state.caches, one_state.caches, slot, pages,
-                self.paged.page_size, self.paged.pages_per_slot_max,
-                scratch_page=self.paged.pool_pages)
-        else:
-            caches = _scatter_rows(self.state.caches, one_state.caches, slot)
-        self.state = ServeState(
-            caches=caches,
-            lengths=self.state.lengths.at[slot].set(one_state.lengths[0]),
-            extras=self.state.extras)
-        self._key, sub = jax.random.split(self._key)
-        tok = int(sample_token(logits, sub, req.temperature)[0])
-        rs = RequestState(req, slot, admitted_at=time.perf_counter())
-        rs.generated.append(tok)
-        self._tokens = self._tokens.at[slot].set(tok)
-        self._slots[slot] = rs
-        self._maybe_finish(rs, tok)
+        syncs = 0
+        with obs_trace.span(
+                "serve.admit", cat="request", uid=req.uid, slot=slot,
+                pages=len(pages) if pages is not None else 0,
+                free=len(self._free_pages) if pages is not None else 0
+        ) as admit:
+            prompt = jnp.asarray(req.prompt, jnp.int32)[None, :]
+            batch = {"tokens": prompt}
+            for k, v in req.extras.items():
+                batch[k] = jnp.asarray(v)[None]
+            t0 = time.perf_counter()
+            with obs_trace.span("serve.prefill", cat="request", uid=req.uid,
+                                prompt_len=len(req.prompt)):
+                one_state, logits = self._prefill(self.params, batch)
+                jax.block_until_ready(logits)
+                syncs += 1
+            self.prefill_s += time.perf_counter() - t0
+            with obs_trace.span("serve.scatter", cat="request", uid=req.uid):
+                if pages is not None:
+                    caches = _scatter_paged_rows(
+                        self.state.caches, one_state.caches, slot, pages,
+                        self.paged.page_size, self.paged.pages_per_slot_max,
+                        scratch_page=self.paged.pool_pages)
+                else:
+                    caches = _scatter_rows(self.state.caches,
+                                           one_state.caches, slot)
+                self.state = ServeState(
+                    caches=caches,
+                    lengths=self.state.lengths.at[slot].set(
+                        one_state.lengths[0]),
+                    extras=self.state.extras)
+            with obs_trace.span("serve.first_token", cat="request",
+                                uid=req.uid):
+                self._key, sub = jax.random.split(self._key)
+                tok = int(sample_token(logits, sub, req.temperature)[0])
+                syncs += 1
+                rs = RequestState(req, slot, admitted_at=time.perf_counter())
+                rs.generated.append(tok)
+                self._tokens = self._tokens.at[slot].set(tok)
+                self._slots[slot] = rs
+                self._maybe_finish(rs, tok)
+            admit.set(syncs=syncs)
+        return syncs
 
-    def _admit(self) -> None:
+    def _admit(self) -> Tuple[int, int]:
+        """Seat queued requests in free slots; returns how many were seated
+        and the host's waits on the device that took."""
         if self.interleave == "drain" and \
                 any(s is not None for s in self._slots):
             # drain policy: only refill once the resident batch empties —
             # the same admission gate the workload simulator prices
-            return
+            return 0, 0
         if self.paged is not None and self.paged.prefill_chunk > 0:
-            self._admit_chunked()
-            return
+            return self._admit_chunked()
+        admitted = syncs = 0
         for slot in self._free_slots():
             if not self.queue:
                 break
@@ -330,15 +346,14 @@ class ContinuousBatcher:
                     break
                 pages = [self._free_pages.pop(0) for _ in range(need)]
                 self._slot_pages[slot] = pages
-                obs_trace.instant("page_reserve", cat="request",
-                                  uid=self.queue[0].uid, pages=need,
-                                  free=len(self._free_pages))
             else:
                 pages = None
             req = self.queue.pop(0)
-            self._prefill_and_seat(req, slot, pages)
+            syncs += self._prefill_and_seat(req, slot, pages)
+            admitted += 1
+        return admitted, syncs
 
-    def _admit_chunked(self) -> None:
+    def _admit_chunked(self) -> Tuple[int, int]:
         """Chunked-prefill admission: one prompt chunk per tick, decode
         ticking underneath.  The jitted prefill still runs once, over the
         full prompt, when the last chunk lands — chunking is a *scheduling*
@@ -352,25 +367,22 @@ class ContinuousBatcher:
                               done=done, prompt_len=len(req.prompt))
             if done >= len(req.prompt):
                 self._prefilling = None
-                self._prefill_and_seat(req, slot, pages)
-            else:
-                self._prefilling[1] = done
-            return
+                return 1, self._prefill_and_seat(req, slot, pages)
+            self._prefilling[1] = done
+            return 0, 0
         free = self._free_slots()
         if not self.queue or not free:
-            return
+            return 0, 0
         need = self.paged.pages_for(self._worst_case_tokens(self.queue[0]))
         if need > len(self._free_pages):
             obs_trace.instant("defer", cat="request", uid=self.queue[0].uid,
                               need=need, free=len(self._free_pages))
-            return
+            return 0, 0
         slot = free[0]
         pages = [self._free_pages.pop(0) for _ in range(need)]
         self._slot_pages[slot] = pages
-        obs_trace.instant("page_reserve", cat="request",
-                          uid=self.queue[0].uid, pages=need,
-                          free=len(self._free_pages))
         self._prefilling = [self.queue.pop(0), 0, slot, pages]
+        return 0, 0
 
     # -- stepping -----------------------------------------------------------
 
@@ -384,8 +396,6 @@ class ContinuousBatcher:
             self._slots[rs.slot] = None
             tr = obs_trace.active()
             if tr is not None:
-                tr.instant("retire", cat="request", uid=rs.request.uid,
-                           generated=len(rs.generated))
                 tr.async_end("request", rs.request.uid,
                              generated=len(rs.generated))
             if self.paged is not None:
@@ -415,10 +425,17 @@ class ContinuousBatcher:
     def tick(self) -> int:
         """Admit + one decode step for all resident requests.
         Returns the number of live requests stepped."""
-        self._admit()
-        live = [s for s in self._slots if s is not None]
-        if not live:
-            return 0
+        with obs_trace.span("serve.tick", cat="serve") as span:
+            admitted, syncs = self._admit()
+            live = [s for s in self._slots if s is not None]
+            if live:
+                syncs += self._step(live)
+            span.set(live=len(live), admitted=admitted, syncs=syncs)
+        return len(live)
+
+    def _step(self, live: List[RequestState]) -> int:
+        """One decode step for the ``live`` slots: decode, sample, and feed
+        each token back.  Returns the host's waits on the device."""
         self.ticks += 1
         self._occupancy_sum += len(live)
         if self.paged is not None:
@@ -430,33 +447,38 @@ class ContinuousBatcher:
         tr = obs_trace.active()
         if tr is not None:
             tr.counter("queue_depth", len(self.queue))
+        syncs = 0
         t0 = time.perf_counter()
-        with obs_trace.span("decode_tick", cat="serve", live=len(live),
+        with obs_trace.span("serve.decode", cat="serve", live=len(live),
                             tick=self.ticks):
             new_state, logits = self._decode(self.params, self.state,
                                              self._tokens[:, None])
             jax.block_until_ready(logits)
+            syncs += 1
         self.decode_s += time.perf_counter() - t0
         self.state = new_state
-        self._key, sub = jax.random.split(self._key)
-        # per-slot temperatures: requests with different sampling settings
-        # share one decode step, so each resident row decodes at its own
-        # temperature (empty slots sample greedily into ignored outputs);
-        # the all-greedy batch — the common replay case — keeps the scalar
-        # argmax-only fast path
-        if any(rs.request.temperature > 0.0 for rs in live):
-            temps = np.zeros((self.num_slots,), np.float32)
+        with obs_trace.span("serve.sample", cat="serve"):
+            self._key, sub = jax.random.split(self._key)
+            # per-slot temperatures: requests with different sampling
+            # settings share one decode step, so each resident row decodes
+            # at its own temperature (empty slots sample greedily into
+            # ignored outputs); the all-greedy batch — the common replay
+            # case — keeps the scalar argmax-only fast path
+            if any(rs.request.temperature > 0.0 for rs in live):
+                temps = np.zeros((self.num_slots,), np.float32)
+                for rs in live:
+                    temps[rs.slot] = rs.request.temperature
+                toks = sample_token(logits, sub, jnp.asarray(temps))
+            else:
+                toks = sample_token(logits, sub, 0.0)
+        with obs_trace.span("serve.feedback", cat="serve"):
             for rs in live:
-                temps[rs.slot] = rs.request.temperature
-            toks = sample_token(logits, sub, jnp.asarray(temps))
-        else:
-            toks = sample_token(logits, sub, 0.0)
-        for rs in list(live):
-            tok = int(toks[rs.slot])
-            rs.generated.append(tok)
-            self._tokens = self._tokens.at[rs.slot].set(tok)
-            self._maybe_finish(rs, tok)
-        return len(live)
+                tok = int(toks[rs.slot])
+                syncs += 1
+                rs.generated.append(tok)
+                self._tokens = self._tokens.at[rs.slot].set(tok)
+                self._maybe_finish(rs, tok)
+        return syncs
 
     def run_until_drained(self, max_ticks: int = 10_000,
                           on_limit: str = "raise") -> List[RequestState]:

@@ -691,12 +691,13 @@ class _FleetReplica:
                 if tr is not None:
                     arrival = reqs[idx].arrival_s * 1e6
                     start = self.clock - t_pref
-                    tr.complete("queue", arrival, max(start - arrival, 0.0),
-                                cat="sim_request", track=obs_trace.TRACK_SIM,
+                    tr.complete("serve.queue", arrival,
+                                max(start - arrival, 0.0), cat="sim_request",
+                                track=obs_trace.TRACK_SIM,
                                 tid=self.trace_tid, uid=reqs[idx].uid)
-                    tr.complete("prefill", start, t_pref, cat="sim_request",
-                                track=obs_trace.TRACK_SIM, tid=self.trace_tid,
-                                uid=reqs[idx].uid,
+                    tr.complete("serve.prefill", start, t_pref,
+                                cat="sim_request", track=obs_trace.TRACK_SIM,
+                                tid=self.trace_tid, uid=reqs[idx].uid,
                                 prompt_len=reqs[idx].prompt_len)
                 self.free_pages -= need
                 self._finish_prefill(idx, need)

@@ -25,8 +25,9 @@ from repro.obs import metrics as obs_metrics
 from repro.obs import report as obs_report
 from repro.obs import trace as obs_trace
 from repro.obs.metrics import MetricsRegistry
+from repro.serving.paging import PagedPlan
 from repro.serving.replay import replay_trace
-from repro.serving.scheduler import ContinuousBatcher
+from repro.serving.scheduler import ContinuousBatcher, Request
 from repro.train.serve_step import jitted_steps
 from repro.tuner.runner import transfer_tune
 from repro.utils.config import RunConfig, ShapeConfig
@@ -43,6 +44,11 @@ SIM_SPEC = ("poisson:rate=2500,horizon=0.02,mean_prompt=32,mean_output=16,"
             "max_len=96")
 REPLAY_SPEC = ("poisson:rate=1500,horizon=0.004,mean_prompt=6,"
                "mean_output=4,max_len=16")
+# the batcher's spans: a tick's phases, and an admission's
+TICK_CHILDREN = ("serve.decode", "serve.sample", "serve.feedback")
+ADMIT_CHILDREN = ("serve.prefill", "serve.scatter", "serve.first_token")
+SERVE_SPANS = ("serve.tick", "serve.admit", "serve.queue") + TICK_CHILDREN \
+    + ADMIT_CHILDREN
 
 
 @pytest.fixture(autouse=True)
@@ -267,10 +273,21 @@ def test_sim_counters_bit_identical_under_tracing():
     assert sim_events
 
 
-def _replay_tokens(served_model, traced: bool):
+# the dense batcher, a paged one, and a paged one admitting in chunks
+PLANS = {
+    "dense": None,
+    "paged": PagedPlan(paging=True, pool_pages=16, page_size=4,
+                       pages_per_slot_max=8),
+    "chunked": PagedPlan(paging=True, pool_pages=16, page_size=4,
+                         pages_per_slot_max=8, prefill_chunk=4),
+}
+
+
+def _replay_tokens(served_model, traced: bool, paged=None):
     cfg, run, model, params = served_model
     trace = make_workload(REPLAY_SPEC).generate(0)
-    b = ContinuousBatcher(model, run, params, num_slots=2, cache_len=32)
+    b = ContinuousBatcher(model, run, params, num_slots=2, cache_len=32,
+                          paged=paged)
     if traced:
         with obs_trace.trace_to(None):
             rep = replay_trace(b, trace, seed=0)
@@ -290,9 +307,11 @@ def served_model():
     return cfg, run, model, params
 
 
-def test_replay_tokens_and_counters_bit_identical_under_tracing(served_model):
-    r0, t0 = _replay_tokens(served_model, traced=False)
-    r1, t1 = _replay_tokens(served_model, traced=True)
+@pytest.mark.parametrize("plan", sorted(PLANS))
+def test_replay_tokens_and_counters_bit_identical_under_tracing(served_model,
+                                                                plan):
+    r0, t0 = _replay_tokens(served_model, traced=False, paged=PLANS[plan])
+    r1, t1 = _replay_tokens(served_model, traced=True, paged=PLANS[plan])
     assert t0 == t1 and t0
     for f in ("completed", "rejected", "ticks", "tokens", "mean_occupancy",
               "queue_depth_mean", "queue_depth_max"):
@@ -337,8 +356,14 @@ def test_traced_sim2real_run_exports_lifecycle_and_tuner(tmp_path, sim2real):
     assert np.isfinite(res.best_y)
     events = obs_report.load_trace(path)  # validates the schema
     names = {e.get("name") for e in events}
-    # per-request lifecycle spans from the real batcher
-    assert {"queue", "prefill", "decode_tick"} <= names
+    # the real batcher's spans, one per phase of a tick or an admission
+    assert set(SERVE_SPANS) <= names
+    # the instants they replace are gone
+    assert not {"admit", "page_reserve", "retire"} & names
+    # the real batcher and the simulator name their lifecycle alike
+    for name in ("serve.queue", "serve.prefill"):
+        tracks = {e["pid"] for e in events if e.get("name") == name}
+        assert {obs_trace.TRACK_SERVE, obs_trace.TRACK_SIM} <= tracks, name
     # async request lifecycles paired by uid
     assert obs_report.request_latencies(events)
     # env deployment spans and per-round tuner events
@@ -347,10 +372,115 @@ def test_traced_sim2real_run_exports_lifecycle_and_tuner(tmp_path, sim2real):
     assert tuner and {"ask", "tell"} <= {e["name"] for e in tuner}
     # the report CLI summarizes it without error
     rep = obs_report.summarize(events)
-    assert rep["lifecycle_us"].get("queue", 0) > 0
+    assert rep["lifecycle_us"].get("serve.queue", 0) > 0
+    assert rep["lifecycle_us"].get("serve.decode", 0) > 0
     assert rep["tuner_rounds"]
     assert obs_report.main([path, "--slo-ms", "30"]) == 0
     assert obs_report.main([path, "--json"]) == 0
+
+
+# --------------------------------------------------------------------------
+# the batcher's spans: phases of a tick, host syncs, the profiler's clock
+# --------------------------------------------------------------------------
+
+def _serve_batcher(served_model, plan, n_requests=3, max_new=4):
+    cfg, run, model, params = served_model
+    b = ContinuousBatcher(model, run, params, num_slots=2, cache_len=32,
+                          paged=PLANS[plan])
+    rng = np.random.default_rng(3)
+    for i in range(n_requests):
+        b.submit(Request(uid=i, prompt=rng.integers(
+            0, cfg.vocab_size, 5 + i, dtype=np.int32),
+            max_new_tokens=max_new))
+    return b
+
+
+def _inside(child, parent):
+    """``child`` lies within ``parent`` on the same track and thread (the
+    tracer rounds times to the nanosecond)."""
+    eps = 1e-3
+    return (child["pid"] == parent["pid"] and child["tid"] == parent["tid"]
+            and child["ts"] >= parent["ts"] - eps
+            and child["ts"] + child["dur"]
+            <= parent["ts"] + parent["dur"] + eps)
+
+
+@pytest.mark.parametrize("plan", sorted(PLANS))
+def test_tick_and_admission_spans_nest_and_count_syncs(served_model, plan):
+    with obs_trace.trace_to(None) as tr:
+        b = _serve_batcher(served_model, plan)
+        b.run_until_drained()
+    spans = [e for e in tr.events()
+             if e["ph"] == "X" and e["name"].startswith("serve.")]
+    ticks = [e for e in spans if e["name"] == "serve.tick"]
+    admits = [e for e in spans if e["name"] == "serve.admit"]
+    assert len(admits) == 3
+    decoded = 0
+    for t in ticks:
+        inner = sorted((e for e in spans if e is not t and _inside(e, t)),
+                       key=lambda e: e["ts"])
+        direct = [e["name"] for e in inner
+                  if e["name"] in TICK_CHILDREN]
+        mine = [e for e in inner if e["name"] == "serve.admit"]
+        assert len(mine) == t["args"]["admitted"]
+        if t["args"]["live"]:
+            decoded += 1
+            assert direct == list(TICK_CHILDREN)   # once each, in order
+            assert inner[-1]["name"] == "serve.feedback"
+        else:
+            assert direct == []
+        # one wait for the decode step, one per live slot's token, and
+        # each admission's own
+        assert t["args"]["syncs"] == (
+            (1 + t["args"]["live"] if t["args"]["live"] else 0)
+            + sum(a["args"]["syncs"] for a in mine))
+    assert decoded == b.ticks
+    assert sum(t["args"]["admitted"] for t in ticks) == 3
+    for a in admits:
+        assert a["args"]["syncs"] == 2
+        assert a["args"]["slot"] in (0, 1)
+        assert (a["args"]["pages"] > 0) == (plan != "dense")
+        inner = sorted((e for e in spans if e is not a and _inside(e, a)),
+                       key=lambda e: e["ts"])
+        assert [e["name"] for e in inner] == list(ADMIT_CHILDREN)
+        assert {e["args"]["uid"] for e in inner} == {a["args"]["uid"]}
+        assert any(_inside(a, t) for t in ticks)
+    queued = [e for e in spans if e["name"] == "serve.queue"]
+    assert sorted(e["args"]["uid"] for e in queued) == [0, 1, 2]
+
+
+def test_serve_spans_reach_the_profiler_host_plane(served_model, tmp_path):
+    from jax.profiler import ProfileData
+
+    b = _serve_batcher(served_model, "paged", n_requests=2, max_new=3)
+    b.run_until_drained()                 # compiled before the session
+    b = _serve_batcher(served_model, "paged", n_requests=2, max_new=3)
+    with jax.profiler.trace(str(tmp_path)), obs_trace.trace_to(None):
+        b.run_until_drained()
+    files = list(tmp_path.rglob("*.xplane.pb"))
+    assert len(files) == 1
+    host = {e.name for plane in ProfileData.from_file(str(files[0])).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events}
+    assert set(SERVE_SPANS) - {"serve.queue"} <= host
+    # modeled-time spans (``complete``) have no wall interval to mirror
+    assert "serve.queue" not in host
+
+
+def test_no_profiler_annotation_without_a_tracer(served_model, monkeypatch):
+    def refuse(*a, **kw):
+        raise AssertionError("TraceAnnotation made with no tracer active")
+
+    b = _serve_batcher(served_model, "paged")
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", refuse)
+    assert not obs_trace.enabled()
+    b.run_until_drained()
+    assert len(b.completed) == 3
+    # the patch is live: an active tracer's span does make one
+    with obs_trace.trace_to(None):
+        with pytest.raises(AssertionError, match="no tracer"):
+            with obs_trace.span("serve.tick"):
+                pass
 
 
 def test_report_cli_rejects_invalid_file(tmp_path):
