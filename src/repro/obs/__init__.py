@@ -9,7 +9,8 @@ and trace reporting.
   ``serve.scatter``, ``serve.first_token``), ``serve.decode``,
   ``serve.sample`` and ``serve.feedback``, with ``serve.queue`` from
   submit to admission; ``serve.tick`` and ``serve.admit`` carry ``syncs``,
-  the times the host waited on the device.
+  the times the host waited on the device: two a decode tick (the step,
+  then one read of every slot's sampled token) and two an admission.
 - :mod:`repro.obs.metrics` — the metrics registry that is the single
   source of truth for discovery-variable names, plus labeled runtime
   instruments.

@@ -14,6 +14,10 @@ Mechanics:
   slots carry a pad token and their outputs are ignored.
 - Prefill runs per admitted request (batch 1) and its cache is scattered
   into the slot's rows of the shared stacked cache.
+- Sampled tokens come back to the host in one read per decode step and
+  are fed back through a host mirror of the decode input (one upload per
+  step): no device op or wait per live slot.  Freed and never-used slots
+  keep the last token they held.
 - Per-request stopping: max_new_tokens or an EOS token id.
 - Fairness/occupancy stats for capacity planning.
 
@@ -200,7 +204,9 @@ class ContinuousBatcher:
             caches=caches,
             lengths=jnp.zeros((num_slots,), jnp.int32),
             extras={})
-        self._tokens = jnp.zeros((num_slots,), jnp.int32)
+        # the decode step's token input, kept on the host: the only place
+        # per-slot tokens are written
+        self._host_tokens = np.zeros((num_slots,), np.int32)
         self._slots: List[Optional[RequestState]] = [None] * num_slots
         self.queue: List[Request] = []
         self.completed: List[RequestState] = []
@@ -313,7 +319,7 @@ class ContinuousBatcher:
                 syncs += 1
                 rs = RequestState(req, slot, admitted_at=time.perf_counter())
                 rs.generated.append(tok)
-                self._tokens = self._tokens.at[slot].set(tok)
+                self._host_tokens[slot] = tok
                 self._slots[slot] = rs
                 self._maybe_finish(rs, tok)
             admit.set(syncs=syncs)
@@ -422,6 +428,13 @@ class ContinuousBatcher:
             one, self.state.caches,
             is_leaf=lambda x: isinstance(x, PagedKVCache)))
 
+    @property
+    def _tokens(self) -> jax.Array:
+        """The decode input on the device, (num_slots,) int32: each live
+        slot's last token; freed and never-used slots keep what they held."""
+        # a copy: the CPU backend may alias a host buffer it is given
+        return jnp.asarray(self._host_tokens.copy())
+
     def tick(self) -> int:
         """Admit + one decode step for all resident requests.
         Returns the number of live requests stepped."""
@@ -434,8 +447,9 @@ class ContinuousBatcher:
         return len(live)
 
     def _step(self, live: List[RequestState]) -> int:
-        """One decode step for the ``live`` slots: decode, sample, and feed
-        each token back.  Returns the host's waits on the device."""
+        """One decode step for the ``live`` slots: decode, sample, read every
+        slot's token in one transfer and feed each back through the host
+        mirror.  Returns the host's waits on the device (two)."""
         self.ticks += 1
         self._occupancy_sum += len(live)
         if self.paged is not None:
@@ -451,8 +465,9 @@ class ContinuousBatcher:
         t0 = time.perf_counter()
         with obs_trace.span("serve.decode", cat="serve", live=len(live),
                             tick=self.ticks):
-            new_state, logits = self._decode(self.params, self.state,
-                                             self._tokens[:, None])
+            new_state, logits = self._decode(
+                self.params, self.state,
+                jnp.asarray(self._host_tokens[:, None].copy()))
             jax.block_until_ready(logits)
             syncs += 1
         self.decode_s += time.perf_counter() - t0
@@ -472,11 +487,12 @@ class ContinuousBatcher:
             else:
                 toks = sample_token(logits, sub, 0.0)
         with obs_trace.span("serve.feedback", cat="serve"):
+            sampled = np.asarray(toks)        # every slot's token, one read
+            syncs += 1
             for rs in live:
-                tok = int(toks[rs.slot])
-                syncs += 1
+                tok = int(sampled[rs.slot])
                 rs.generated.append(tok)
-                self._tokens = self._tokens.at[rs.slot].set(tok)
+                self._host_tokens[rs.slot] = tok
                 self._maybe_finish(rs, tok)
         return syncs
 

@@ -3,6 +3,7 @@ exports, the metrics registry as single source of truth for the
 discovery-variable names, bit-identity of traced vs untraced runs,
 MetricsLogger lifecycle, kernel-dispatch profiling, and the report CLI."""
 
+import contextlib
 import json
 import threading
 
@@ -429,10 +430,10 @@ def test_tick_and_admission_spans_nest_and_count_syncs(served_model, plan):
             assert inner[-1]["name"] == "serve.feedback"
         else:
             assert direct == []
-        # one wait for the decode step, one per live slot's token, and
-        # each admission's own
+        # one wait for the decode step, one read of every slot's token,
+        # and each admission's own
         assert t["args"]["syncs"] == (
-            (1 + t["args"]["live"] if t["args"]["live"] else 0)
+            (2 if t["args"]["live"] else 0)
             + sum(a["args"]["syncs"] for a in mine))
     assert decoded == b.ticks
     assert sum(t["args"]["admitted"] for t in ticks) == 3
@@ -447,6 +448,42 @@ def test_tick_and_admission_spans_nest_and_count_syncs(served_model, plan):
         assert any(_inside(a, t) for t in ticks)
     queued = [e for e in spans if e["name"] == "serve.queue"]
     assert sorted(e["args"]["uid"] for e in queued) == [0, 1, 2]
+
+
+def _tick_by_tick(served_model, live: int, traced: bool):
+    """Drive 4 slots holding ``live`` requests to the end; per tick, every
+    request's ``generated`` list, and the traced run's ``serve.tick``s."""
+    cfg, run, model, params = served_model
+    b = ContinuousBatcher(model, run, params, num_slots=4, cache_len=32)
+    rng = np.random.default_rng(5)
+    per_tick = []
+    tracing = obs_trace.trace_to(None) if traced else contextlib.nullcontext()
+    with tracing as tr:
+        for i in range(live):
+            b.submit(Request(uid=i, prompt=rng.integers(
+                0, cfg.vocab_size, 4 + i, dtype=np.int32), max_new_tokens=6))
+        while b.tick():
+            states = [s for s in b._slots if s is not None] + b.completed
+            per_tick.append({rs.request.uid: list(rs.generated)
+                             for rs in states})
+    ticks = [e for e in tr.events() if e["name"] == "serve.tick"] \
+        if traced else []
+    return per_tick, ticks
+
+
+@pytest.mark.parametrize("live", [1, 2, 4])
+def test_decode_tick_reads_tokens_once_whatever_the_live_count(served_model,
+                                                               live):
+    per_tick, ticks = _tick_by_tick(served_model, live, traced=True)
+    assert len(ticks) == len(per_tick) + 1     # the last tick finds none
+    for t in ticks[:-1]:
+        # the decode wait and one read of every slot's token; the first
+        # tick adds its admissions' two each
+        admitted = t["args"]["admitted"]
+        assert t["args"]["syncs"] == 2 + 2 * admitted, t["args"]
+    assert ticks[0]["args"]["admitted"] == live
+    assert all(t["args"]["live"] == live for t in ticks[:-1])
+    assert per_tick == _tick_by_tick(served_model, live, traced=False)[0]
 
 
 def test_serve_spans_reach_the_profiler_host_plane(served_model, tmp_path):

@@ -1,6 +1,7 @@
 """Continuous-batching scheduler: correctness vs the single-request
 generate() path, slot reuse, EOS/max-token stopping, occupancy, admission
-edge cases, and drain-stall detection."""
+edge cases, drain-stall detection, and token feedback through the host
+mirror against the per-slot device loop."""
 
 import jax
 import jax.numpy as jnp
@@ -8,9 +9,11 @@ import numpy as np
 import pytest
 
 from conftest import tiny_model_config
+from repro.configs.registry import get_smoke_config
 from repro.models.model import build_model
+from repro.serving.paging import PagedPlan
 from repro.serving.scheduler import ContinuousBatcher, DrainStall, Request
-from repro.train.serve_step import generate
+from repro.train.serve_step import generate, sample_token
 from repro.utils.config import RunConfig, ShapeConfig
 
 
@@ -215,3 +218,119 @@ def test_run_until_drained_warn_flags_partial(served):
     assert b.stalled and done == []
     with pytest.raises(ValueError, match="on_limit"):
         b.run_until_drained(on_limit="bogus")
+
+
+# --------------------------------------------------------------------------
+# token feedback: the host mirror against the per-slot device loop
+# --------------------------------------------------------------------------
+
+class PerSlotFeedbackBatcher(ContinuousBatcher):
+    """The oracle: tokens fed back as the batcher once did, into a device
+    vector with a read and an eager scatter per live slot (the first token
+    of an admission too)."""
+
+    def __init__(self, *args, **kw):
+        super().__init__(*args, **kw)
+        self._dev_tokens = jnp.zeros((self.num_slots,), jnp.int32)
+
+    @property
+    def _tokens(self):
+        return self._dev_tokens
+
+    def _prefill_and_seat(self, req, slot, pages):
+        syncs = super()._prefill_and_seat(req, slot, pages)
+        rs = self._slots[slot] or self.completed[-1]
+        self._dev_tokens = self._dev_tokens.at[slot].set(rs.generated[0])
+        return syncs
+
+    def _step(self, live):
+        self.ticks += 1
+        self._occupancy_sum += len(live)
+        new_state, logits = self._decode(self.params, self.state,
+                                         self._dev_tokens[:, None])
+        jax.block_until_ready(logits)
+        self.state = new_state
+        self._key, sub = jax.random.split(self._key)
+        if any(rs.request.temperature > 0.0 for rs in live):
+            temps = np.zeros((self.num_slots,), np.float32)
+            for rs in live:
+                temps[rs.slot] = rs.request.temperature
+            toks = sample_token(logits, sub, jnp.asarray(temps))
+        else:
+            toks = sample_token(logits, sub, 0.0)
+        for rs in live:
+            tok = int(toks[rs.slot])
+            rs.generated.append(tok)
+            self._dev_tokens = self._dev_tokens.at[rs.slot].set(tok)
+            self._maybe_finish(rs, tok)
+        return 1 + len(live)
+
+
+# (tick, request): two at the start, two more mid-flight, greedy and hot
+# requests sharing steps, never more than three of the four slots live
+FEEDBACK_TRAFFIC = [
+    (0, dict(uid=0, prompt=[3, 1, 4, 1, 5], max_new_tokens=9)),
+    (0, dict(uid=1, prompt=[2, 7], max_new_tokens=4, temperature=3.0)),
+    (2, dict(uid=2, prompt=[6, 2, 8], max_new_tokens=5)),
+    (5, dict(uid=3, prompt=[1, 4, 1, 4, 2], max_new_tokens=6,
+             temperature=1.5)),
+]
+
+
+def _feedback_batchers(model_name, plan):
+    if model_name == "moe":
+        # rows of one decode step share the router's groups, so an empty
+        # row's input could reach a live row through expert capacity
+        cfg = get_smoke_config("llama4-maverick-400b-a17b")
+    else:
+        cfg = tiny_model_config()
+    run = RunConfig(model=cfg, shape=ShapeConfig("s", 64, 4, "decode"))
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    paged = PagedPlan(paging=True, pool_pages=16, page_size=4,
+                      pages_per_slot_max=8) if plan == "paged" else None
+
+    def make(cls, eos=None):
+        return cls(model, run, params, num_slots=4, cache_len=32, seed=11,
+                   paged=paged, eos_token=eos)
+    return make
+
+
+def _drive(batchers):
+    """Tick the batchers side by side over FEEDBACK_TRAFFIC; after every
+    tick, each one's tokens per request and its decode input."""
+    for b in batchers:
+        b.ticks_seen = []
+    for tick in range(40):
+        for at, kw in FEEDBACK_TRAFFIC:
+            if at == tick:
+                for b in batchers:
+                    b.submit(Request(**{**kw, "prompt": np.asarray(
+                        kw["prompt"], np.int32)}))
+        for b in batchers:
+            b.tick()
+            states = [s for s in b._slots if s is not None] + b.completed
+            b.ticks_seen.append((
+                {rs.request.uid: list(rs.generated) for rs in states},
+                np.asarray(b._tokens)))
+    return [b.ticks_seen for b in batchers]
+
+
+@pytest.mark.parametrize("model_name,plan", [
+    ("tiny", "dense"), ("tiny", "paged"), ("moe", "dense"), ("moe", "paged")])
+def test_host_mirror_feeds_back_what_per_slot_loop_did(model_name, plan):
+    make = _feedback_batchers(model_name, plan)
+    # stop uid 0 on EOS: its third token, unless that came sooner
+    (probe,) = _drive([make(ContinuousBatcher)])
+    eos = probe[-1][0][0][2]
+    new, old = _drive([make(ContinuousBatcher, eos),
+                       make(PerSlotFeedbackBatcher, eos)])
+    for (gen_new, tok_new), (gen_old, tok_old) in zip(new, old):
+        assert gen_new == gen_old
+        np.testing.assert_array_equal(tok_new, tok_old)   # empty rows too
+    final = new[-1][0]
+    assert sorted(final) == [0, 1, 2, 3]
+    assert len(final[0]) < 9 and final[0][-1] == eos
+    assert all(len(g) == kw["max_new_tokens"] or g[-1] == eos
+               for (_, kw), g in zip(FEEDBACK_TRAFFIC, map(final.get,
+                                                           range(4))))
