@@ -1,4 +1,4 @@
-"""Architecture registry: the 10 assigned archs, shape cells, run assembly,
+"""Architecture registry: the assigned archs, shape cells, run assembly,
 and ShapeDtypeStruct input factories for the dry-run.
 
 ``--arch <id>`` everywhere resolves through ``get_model_config``.
@@ -28,6 +28,7 @@ _ARCH_MODULES: Dict[str, str] = {
     "llama4-maverick-400b-a17b": "repro.configs.llama4_maverick_400b",
     "llama-3.2-vision-11b": "repro.configs.llama3p2_vision_11b",
     "whisper-large-v3": "repro.configs.whisper_large_v3",
+    "jamba2-3b": "repro.configs.jamba2_3b",
 }
 
 # archs whose second-moment state must be factored to fit HBM at 512 chips

@@ -82,8 +82,9 @@ def apply_gqa(
     q = jnp.einsum("bsd,de->bse", x, p["wq"]).reshape(b, s, cfg.num_heads, hd)
     k = jnp.einsum("bsd,de->bse", x, p["wk"]).reshape(b, s, cfg.num_kv_heads, hd)
     v = jnp.einsum("bsd,de->bse", x, p["wv"]).reshape(b, s, cfg.num_kv_heads, hd)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    if cfg.rope_theta:  # 0: no positional encoding (jamba)
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
 
     if decode and isinstance(cache, PagedKVCache):
         assert s == 1

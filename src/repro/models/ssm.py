@@ -21,7 +21,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops
-from repro.models.layers import dense_init
+from repro.kernels.rmsnorm.ref import rmsnorm_ref
+from repro.models.layers import dense_init, init_rmsnorm
 from repro.utils.config import ModelConfig
 
 
@@ -68,7 +69,7 @@ def init_mamba1(key, cfg: ModelConfig, dtype) -> Dict:
     n, rank, k = cfg.ssm_state, _dt_rank(cfg), cfg.ssm_conv
     ks = jax.random.split(key, 7)
     A = -jnp.tile(jnp.arange(1, n + 1, dtype=jnp.float32)[None, :], (d_inner, 1))
-    return {
+    p = {
         "w_x": dense_init(ks[0], cfg.d_model, d_inner, dtype),
         "w_z": dense_init(ks[1], cfg.d_model, d_inner, dtype),
         "conv_w": (jax.random.normal(ks[2], (k, d_inner), jnp.float32)
@@ -81,14 +82,30 @@ def init_mamba1(key, cfg: ModelConfig, dtype) -> Dict:
         "D": jnp.ones((d_inner,), jnp.float32),
         "w_out": dense_init(ks[6], d_inner, cfg.d_model, dtype),
     }
+    if cfg.ssm_dt_bc_norm:
+        p.update(dt_norm=init_rmsnorm(rank, dtype),
+                 b_norm=init_rmsnorm(n, dtype), c_norm=init_rmsnorm(n, dtype))
+    return p
+
+
+def _dt_b_c(p: Dict, cfg: ModelConfig, xdbc: jax.Array):
+    """x_proj's output split into [dt_low | B | C], each through its weighted
+    RMS norm where the config has them (jamba)."""
+    n, rank = cfg.ssm_state, _dt_rank(cfg)
+    parts = (xdbc[..., :rank], xdbc[..., rank:rank + n], xdbc[..., rank + n:])
+    if not cfg.ssm_dt_bc_norm:
+        return parts
+    # plain XLA, fused with the projection around it: rows of 160 and 16
+    # are too narrow for a kernel launch of their own
+    return tuple(rmsnorm_ref(x, p[k]["scale"], cfg.norm_eps)
+                 for x, k in zip(parts, ("dt_norm", "b_norm", "c_norm")))
 
 
 def apply_mamba1(p: Dict, cfg: ModelConfig, x: jax.Array,
                  state: Optional[MambaState] = None, decode: bool = False,
                  return_state: bool = False
                  ) -> Tuple[jax.Array, Optional[MambaState]]:
-    n, rank = cfg.ssm_state, _dt_rank(cfg)
-    b, s, _ = x.shape
+    s = x.shape[1]
     xi = jnp.einsum("bsd,de->bse", x, p["w_x"])
     z = jnp.einsum("bsd,de->bse", x, p["w_z"])
     A = -jnp.exp(p["A_log"])
@@ -97,8 +114,7 @@ def apply_mamba1(p: Dict, cfg: ModelConfig, x: jax.Array,
         assert state is not None and s == 1
         conv_state, y_t = _causal_conv_step(state.conv, xi[:, 0], p["conv_w"], p["conv_b"])
         u = jax.nn.silu(y_t)  # (B, C)
-        xdbc = jnp.einsum("bc,ce->be", u, p["w_bcdt"])
-        dt_low, Bc, Cc = xdbc[..., :rank], xdbc[..., rank:rank + n], xdbc[..., rank + n:]
+        dt_low, Bc, Cc = _dt_b_c(p, cfg, jnp.einsum("bc,ce->be", u, p["w_bcdt"]))
         dt = jax.nn.softplus(jnp.einsum("br,rc->bc", dt_low, p["w_dt"])
                              + p["dt_bias"][None, :])
         ssm_state, y = ops.selective_scan_step(state.ssm, u, dt, A, Bc, Cc, p["D"])
@@ -107,8 +123,7 @@ def apply_mamba1(p: Dict, cfg: ModelConfig, x: jax.Array,
         return out, MambaState(conv_state, ssm_state)
 
     u = jax.nn.silu(_causal_conv_seq(xi, p["conv_w"], p["conv_b"]))
-    xdbc = jnp.einsum("bsc,ce->bse", u, p["w_bcdt"])
-    dt_low, Bc, Cc = xdbc[..., :rank], xdbc[..., rank:rank + n], xdbc[..., rank + n:]
+    dt_low, Bc, Cc = _dt_b_c(p, cfg, jnp.einsum("bsc,ce->bse", u, p["w_bcdt"]))
     dt = jax.nn.softplus(jnp.einsum("bsr,rc->bsc", dt_low, p["w_dt"])
                          + p["dt_bias"][None, None, :])
     new_state = None

@@ -2,7 +2,8 @@
 
 Layers are organized as *super-blocks*: the smallest repeating pattern of
 sub-layers (e.g. llama4 = [dense, moe], zamba2 = 5x[mamba2] + [mamba2+shared
-attention], vlm = 4x[dense] + [cross]).  Super-block weights are stacked on a
+attention], jamba = 7x[mamba1+mlp] + [dense] + 6x[mamba1+mlp], vlm =
+4x[dense] + [cross]).  Super-block weights are stacked on a
 leading axis and iterated with ``jax.lax.scan`` so that 61-layer 671B configs
 lower to compact HLO.  Decode threads stacked per-super-block caches through
 the same scan.
@@ -32,6 +33,9 @@ from repro.utils.config import ModelConfig, ParallelConfig
 
 def block_pattern(cfg: ModelConfig) -> List[str]:
     """Sub-layer kinds within one super-block."""
+    if cfg.attn_layer_period:
+        return ["dense" if i == cfg.attn_layer_offset else "mamba1"
+                for i in range(cfg.attn_layer_period)]
     if cfg.family == "ssm":
         return ["mamba1"]
     if cfg.family == "hybrid":
@@ -66,8 +70,13 @@ def _init_attn(key, cfg: ModelConfig, dtype) -> Dict:
 def _init_sublayer(key, cfg: ModelConfig, kind: str, dtype) -> Dict:
     ks = jax.random.split(key, 4)
     if kind == "mamba1":
-        return {"norm": init_rmsnorm(cfg.d_model, dtype),
-                "mixer": ssm.init_mamba1(ks[0], cfg, dtype)}
+        p = {"norm": init_rmsnorm(cfg.d_model, dtype),
+             "mixer": ssm.init_mamba1(ks[0], cfg, dtype)}
+        if cfg.d_ff:
+            p["mlp_norm"] = init_rmsnorm(cfg.d_model, dtype)
+            p["mlp"] = init_mlp(ks[1], cfg.d_model, cfg.d_ff, cfg.mlp_type,
+                                dtype)
+        return p
     if kind in ("mamba2", "mamba2_shared_attn"):
         return {"norm": init_rmsnorm(cfg.d_model, dtype),
                 "mixer": ssm.init_mamba2(ks[0], cfg, dtype)}
@@ -102,7 +111,7 @@ def init_lm_params(cfg: ModelConfig, key: jax.Array, dtype) -> Dict[str, Any]:
         "blocks": jax.vmap(init_superblock)(jax.random.split(k_blocks, nsb)),
         "final_norm": init_rmsnorm(cfg.d_model, dtype),
     }
-    if cfg.family == "hybrid":
+    if "mamba2_shared_attn" in pat:
         ks = jax.random.split(k_shared, 2)
         params["shared_attn"] = {
             "norm": init_rmsnorm(cfg.d_model, dtype),
@@ -208,7 +217,12 @@ def _apply_sublayer(sub_p, cfg, par, kind, h, positions, shared_p, vision_kv,
                                  apply_rmsnorm(sub_p["norm"], h, cfg.norm_eps),
                                  state=cache if decode else None, decode=decode,
                                  return_state=use_cache and not decode)
-        return h + y, (st if use_cache else cache), aux
+        h = h + y
+        if cfg.d_ff:
+            h = h + apply_mlp(sub_p["mlp"],
+                              apply_rmsnorm(sub_p["mlp_norm"], h, cfg.norm_eps),
+                              cfg.mlp_type)
+        return h, (st if use_cache else cache), aux
     if kind in ("mamba2", "mamba2_shared_attn"):
         mixer_cache = cache["mixer"] if (decode and isinstance(cache, dict)) else None
         y, st = ssm.apply_mamba2(sub_p["mixer"], cfg,

@@ -11,6 +11,9 @@ and trace reporting.
   submit to admission; ``serve.tick`` and ``serve.admit`` carry ``syncs``,
   the times the host waited on the device: two a decode tick (the step,
   then one read of every slot's sampled token) and two an admission.
+  ``serve.admit`` carries the ``pages`` it reserved (KV pages, where the
+  batcher pages an attention KV cache); there ``serve.tick`` also carries
+  ``kv_pages_held``, the pool pages its live slots hold when it ends.
 - :mod:`repro.obs.metrics` — the metrics registry that is the single
   source of truth for discovery-variable names, plus labeled runtime
   instruments.

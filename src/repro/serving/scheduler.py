@@ -189,7 +189,13 @@ class ContinuousBatcher:
                 self.paged.pages_per_slot_max)
             self._free_pages: List[int] = list(range(self.paged.pool_pages))
             self._slot_pages: List[List[int]] = [[] for _ in range(num_slots)]
+            # pages hold attention KV, not only bookkeeping for recurrent
+            # state: the ticks then count the pages held
+            self._kv_paged = any(
+                isinstance(c, PagedKVCache) for c in jax.tree.leaves(
+                    caches, is_leaf=lambda x: isinstance(x, PagedKVCache)))
         else:
+            self._kv_paged = False
             self.cache_len = cache_len
             caches = model.init_decode_state(num_slots, cache_len)
 
@@ -444,6 +450,9 @@ class ContinuousBatcher:
             if live:
                 syncs += self._step(live)
             span.set(live=len(live), admitted=admitted, syncs=syncs)
+            if self._kv_paged:
+                span.set(kv_pages_held=(self.paged.pool_pages
+                                        - len(self._free_pages)))
         return len(live)
 
     def _step(self, live: List[RequestState]) -> int:

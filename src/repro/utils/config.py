@@ -42,7 +42,7 @@ class ModelConfig:
     # activation / norm
     mlp_type: str = "swiglu"  # swiglu | relu2 | gelu
     norm_eps: float = 1e-5
-    rope_theta: float = 10000.0
+    rope_theta: float = 10000.0  # 0 -> no rotary embedding (NoPE)
     tie_embeddings: bool = False
     attn_logit_softcap: float = 0.0
 
@@ -71,9 +71,17 @@ class ModelConfig:
     ssm_expand: int = 2
     ssm_num_heads: int = 0  # mamba2 heads; 0 -> mamba1
     ssm_chunk: int = 256
+    # weighted RMS norms on the Mamba-1 mixer's dt, B and C, after x_proj
+    # (jamba)
+    ssm_dt_bc_norm: bool = False
     # hybrid: attention block applied every `hybrid_attn_period` layers,
     # sharing one set of weights (zamba2-style shared block).
     hybrid_attn_period: int = 0
+    # hybrid, jamba-style: layer i is attention + MLP when
+    # i % attn_layer_period == attn_layer_offset, else Mamba-1 (+ MLP when
+    # d_ff > 0); 0 -> no such interleave
+    attn_layer_period: int = 0
+    attn_layer_offset: int = 0
 
     # VLM cross-attention
     cross_attn_period: int = 0  # every k-th layer has cross-attention
@@ -92,6 +100,11 @@ class ModelConfig:
     def __post_init__(self):
         if self.head_dim == 0 and self.num_heads:
             object.__setattr__(self, "head_dim", self.d_model // self.num_heads)
+        if self.attn_layer_period and not \
+                0 <= self.attn_layer_offset < self.attn_layer_period:
+            raise ValueError(
+                f"attn_layer_offset {self.attn_layer_offset} outside the "
+                f"period {self.attn_layer_period}")
 
     # -- derived sizes ----------------------------------------------------
     @property

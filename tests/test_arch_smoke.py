@@ -90,6 +90,7 @@ def test_full_config_param_counts_match_public_scale():
         "llama4-maverick-400b-a17b": (350e9, 450e9),
         "llama-3.2-vision-11b": (8e9, 13e9),
         "whisper-large-v3": (1.2e9, 2.2e9),
+        "jamba2-3b": (2.9e9, 3.2e9),
     }
     for arch, (lo, hi) in expect.items():
         n = get_model_config(arch).param_count()

@@ -55,7 +55,7 @@ def test_long_500k_skips_are_exactly_the_full_attention_archs():
         "whisper-large-v3"])
     runnable = [a for a in list_archs() if "long_500k" in arch_shapes(a)]
     assert sorted(runnable) == sorted(
-        ["falcon-mamba-7b", "zamba2-2.7b", "h2o-danube-1.8b"])
+        ["falcon-mamba-7b", "zamba2-2.7b", "h2o-danube-1.8b", "jamba2-3b"])
 
 
 def test_make_run_rejects_long500k_for_full_attention():

@@ -13,6 +13,8 @@ from repro.kernels.flash_attention.kernel import (
     decode_attention_pallas, flash_attention_pallas)
 from repro.kernels.mamba_scan import ref as sref
 from repro.kernels.mamba_scan.kernel import selective_scan_pallas
+from repro.kernels.paged_attention import ref as pref
+from repro.kernels.paged_attention.kernel import paged_decode_attention_pallas
 from repro.kernels.rmsnorm import ref as rref
 from repro.kernels.rmsnorm.kernel import rmsnorm_pallas
 from repro.kernels.ssd import ref as ssdref
@@ -85,6 +87,21 @@ def test_decode_attention_matches_oracle(sw):
     ref = aref.decode_attention_ref(q, kc, vc, clen, sliding_window=sw)
     out = decode_attention_pallas(q, kc, vc, clen, sliding_window=sw,
                                   kv_block=32, interpret=True)
+    np.testing.assert_allclose(out, ref, atol=2e-5, rtol=1e-4)
+
+
+def test_paged_decode_five_heads_over_one_kv_head():
+    # jamba's group at smoke size: 5 query heads over 1 KV head, a (5, D)
+    # query block that is the whole head axis; pages scattered over the pool
+    b, hq, d, ps, n_pages = 3, 5, 128, 8, 4
+    q = rand(b, 1, hq, d)
+    k_pages, v_pages = rand(13, 1, ps, d), rand(13, 1, ps, d)
+    table = jnp.asarray(RNG.permutation(13)[:b * n_pages].reshape(b, n_pages),
+                        jnp.int32)
+    clen = jnp.asarray([1, 17, 32], jnp.int32)
+    ref = pref.paged_decode_attention_ref(q, k_pages, v_pages, table, clen)
+    out = paged_decode_attention_pallas(q, k_pages, v_pages, table, clen,
+                                        interpret=True)
     np.testing.assert_allclose(out, ref, atol=2e-5, rtol=1e-4)
 
 

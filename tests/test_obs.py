@@ -450,6 +450,51 @@ def test_tick_and_admission_spans_nest_and_count_syncs(served_model, plan):
     assert sorted(e["args"]["uid"] for e in queued) == [0, 1, 2]
 
 
+@pytest.mark.parametrize("plan", sorted(PLANS))
+def test_admissions_and_ticks_count_kv_pages(served_model, plan):
+    """Where the batcher pages a KV cache, ``serve.admit``'s ``pages`` are
+    the KV pages it reserved and ``serve.tick`` gives the pool pages its
+    live slots hold when the tick ends; the dense path gives neither."""
+    with obs_trace.trace_to(None) as tr:
+        b = _serve_batcher(served_model, plan)
+        b.run_until_drained()
+    spans = [e for e in tr.events() if e["ph"] == "X"]
+    admits = [e["args"] for e in spans if e["name"] == "serve.admit"]
+    ticks = [e["args"] for e in spans if e["name"] == "serve.tick"]
+    if plan == "dense":
+        assert all(a["pages"] == 0 for a in admits)
+        assert not any("kv_pages_held" in t for t in ticks)
+        return
+    assert all(a["pages"] > 0 and "kv_pages" not in a for a in admits)
+    held = [t["kv_pages_held"] for t in ticks]
+    if plan == "paged":
+        # two slots: the first tick seats two requests, which outlive it
+        assert ticks[0]["admitted"] == 2
+        assert held[0] == admits[0]["pages"] + admits[1]["pages"]
+    assert max(held) >= max(a["pages"] for a in admits)
+    assert all(0 <= h <= 16 for h in held)
+    assert held[-1] == 0                        # every request has left
+
+
+def test_kv_page_counts_absent_without_a_kv_cache():
+    """An attention-free model's pages are bookkeeping: no KV count."""
+    cfg = tiny_model_config(family="ssm", attn_type="none", num_heads=0,
+                            num_kv_heads=0, d_ff=0, ssm_state=4, ssm_chunk=4)
+    run = RunConfig(model=cfg, shape=ShapeConfig("s", 64, 2, "decode"))
+    model = build_model(cfg)
+    b = ContinuousBatcher(model, run, model.init(jax.random.PRNGKey(0)),
+                          num_slots=2, paged=PLANS["paged"])
+    b.submit(Request(uid=0, prompt=np.arange(5, dtype=np.int32),
+                     max_new_tokens=3))
+    with obs_trace.trace_to(None) as tr:
+        b.run_until_drained()
+    spans = [e for e in tr.events() if e["ph"] == "X"]
+    admits = [e["args"] for e in spans if e["name"] == "serve.admit"]
+    assert len(admits) == 1 and admits[0]["pages"] > 0
+    assert not any("kv_pages_held" in e["args"] for e in spans
+                   if e["name"] == "serve.tick")
+
+
 def _tick_by_tick(served_model, live: int, traced: bool):
     """Drive 4 slots holding ``live`` requests to the end; per tick, every
     request's ``generated`` list, and the traced run's ``serve.tick``s."""

@@ -72,6 +72,14 @@ def test_flash_prefill_compiles(one_chip, b, s, hq, hkv, window):
                                  ((b, s, hkv, 80), BF16)))
 
 
+def test_flash_prefill_one_kv_head_compiles(one_chip):
+    # jamba2-3b: a 12288-token prompt, 20 query heads of 128 over 1 KV head
+    fn = lambda q, k, v: flash_attention_pallas(q, k, v, causal=True)
+    _assert_kernel(_compile_text(fn, one_chip, ((1, 12288, 20, 128), BF16),
+                                 ((1, 12288, 1, 128), BF16),
+                                 ((1, 12288, 1, 128), BF16)))
+
+
 def test_dense_decode_compiles(one_chip):
     # h2o-danube-1.8b: 8 slots, GQA 32/8, a 1024-token heads-major cache
     fn = lambda q, k, v, n: decode_attention_pallas(q, k, v, n)
@@ -87,6 +95,16 @@ def test_paged_decode_compiles(one_chip):
                                  ((129, 32, 64, 80), BF16),
                                  ((129, 32, 64, 80), BF16),
                                  ((8, 8), I32), ((8,), I32)))
+
+
+def test_paged_decode_group_of_twenty_compiles(one_chip):
+    # jamba2-3b: 32 slots x 104 pages of 128, 3328 pool pages + scratch,
+    # 20 query heads over 1 KV head of 128
+    fn = lambda q, k, v, t, n: paged_decode_attention_pallas(q, k, v, t, n)
+    _assert_kernel(_compile_text(fn, one_chip, ((32, 1, 20, 128), BF16),
+                                 ((3329, 1, 128, 128), BF16),
+                                 ((3329, 1, 128, 128), BF16),
+                                 ((32, 104), I32), ((32,), I32)))
 
 
 @pytest.mark.parametrize("with_state", [False, True])
